@@ -27,6 +27,7 @@ import sys
 import traceback
 
 from benchmarks import common
+from repro.compile_cache import use_compile_cache
 from benchmarks.common import OVERLAP_MIN_CPUS, PAYLOAD_LOSSLESS_FLOOR
 
 #: required BENCH_PR5.json sections; --check fails on a missing/empty one
@@ -278,6 +279,7 @@ def main() -> None:
                          "the overlap/wire acceptance gates hold (--chaos: "
                          "every fault class recovered bit-identically)")
     args = ap.parse_args()
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     failed = []

@@ -19,7 +19,10 @@ from repro.core import (
     SSSP, ChannelConfig, EngineConfig, GraphDEngine, GraphDJob, HashMin,
     MemoryBudget, PageRank, StreamConfig, plan,
 )
+from repro.compile_cache import use_compile_cache
 from repro.graph import partition_graph_streamed, recode_ids, rmat_graph
+
+use_compile_cache()
 
 graph = rmat_graph(scale=12, edge_factor=8, seed=42, directed=False,
                    sparse_ids=True)

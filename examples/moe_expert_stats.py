@@ -8,11 +8,13 @@ drops, and the load-balance aux loss on a reduced qwen3-moe config.
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.data.tokens import synthetic_batch
 from repro.models.moe import moe_ffn
 from repro.models.transformer import init_params
 
+use_compile_cache()
 cfg = get_config("qwen3-moe-235b-a22b").reduced()
 params = init_params(cfg, jax.random.key(0))
 moe_params = jax.tree.map(lambda p: p[0], params["groups"][0]["ffn"])

@@ -9,8 +9,11 @@ structured result.
     PYTHONPATH=src python examples/quickstart.py
 """
 
+from repro.compile_cache import use_compile_cache
 from repro.core import GraphDJob, MemoryBudget, PageRank, plan
 from repro.graph import rmat_graph
+
+use_compile_cache()
 
 # 1. load a graph (here: generated; loaders accept any edge list with
 #    arbitrary 64-bit vertex ids — the recoding pass densifies them)

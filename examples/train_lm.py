@@ -5,8 +5,11 @@ a few hundred steps on the synthetic pipeline, with checkpoint + resume.
 """
 
 import argparse
+import os
 import subprocess
 import sys
+
+from repro.compile_cache import CACHE_ENV, cache_dir
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=300)
@@ -18,4 +21,5 @@ subprocess.run(
      "--steps", str(args.steps), "--batch", "8", "--seq", "128",
      "--ckpt-every", "100"],
     check=True,
+    env=dict(os.environ, **{CACHE_ENV: cache_dir()}),
 )

@@ -268,8 +268,11 @@ class FileCoordinator:
         net = dict(net_send_s=0.0, net_stall_s=0.0, net_recv_s=0.0,
                    net_recv_stall_s=0.0, net_wire_bytes=0.0,
                    net_frames=0.0)
+        devices = []  # each worker's {shard, platform, kind}
         for w in sorted(arrivals):
             rec = arrivals[w]
+            if rec.get("device"):
+                devices.append(dict(shard=int(w), **rec["device"]))
             n_active += int(rec["n_active"])
             n_msgs += int(rec["n_msgs"])
             agg += float(rec["agg"])
@@ -279,7 +282,8 @@ class FileCoordinator:
             for key in net:
                 net[key] += float(rec.get(key, 0.0))
         return dict(n_active=n_active, n_msgs=n_msgs, agg=agg,
-                    active_blocks=blocks, **residency, **net)
+                    active_blocks=blocks, **residency, **net,
+                    devices=devices)
 
     def publish_commit(self, step: int, totals: dict, *, halt: bool,
                        ckpt_landed: bool) -> dict:

@@ -71,7 +71,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.api import Combiner, ShardContext, VertexProgram
 from repro.core.config import MODES, ConfigError, EngineConfig
 from repro.graph.partition import PartitionedGraph
@@ -913,7 +912,7 @@ class GraphDEngine:
             return init_spmd(program, pg_, axis=axis)
 
         if backend == "pallas":
-            step_fn = jax.jit(self._wrap_kl(_pallas))
+            step_fn = self._step_pallas = jax.jit(self._wrap_kl(_pallas))
             self._step_dense = lambda pg_, v, a, s: step_fn(pg_, self.kl, v, a, s)
             self._step_sparse = self._step_dense  # skip is always-on in-kernel
         else:
@@ -927,6 +926,22 @@ class GraphDEngine:
             jax.jit(self._wrap_logged(_logged)) if message_log is not None else None
         )
         self._init = jax.jit(self._wrap(_init, n_in=1, n_stats=0))
+
+    def lower_step(self, values, active, step=0, *, pg=None, kl=None):
+        """The dense superstep lowered for these arguments, not run.
+        ``pg``/``kl`` default to the engine's own; any argument may be a
+        ``jax.ShapeDtypeStruct`` placed on a described device, which
+        compiles for that device. ``.compile().as_text()`` shows what runs
+        — a compiled Pallas kernel appears as a ``tpu_custom_call``."""
+        if self._step_dense is None:
+            raise ValueError("mode='streamed' has no dense superstep")
+        pg = self.pg if pg is None else pg
+        if isinstance(step, int):
+            step = jnp.int32(step)
+        if self.backend == "pallas":
+            kl = self.kl if kl is None else kl
+            return self._step_pallas.lower(pg, kl, values, active, step)
+        return self._step_dense.lower(pg, values, active, step)
 
     # -- vmap / shard_map wrapping ------------------------------------------
     def _wrap(self, fn, n_in: int, n_stats: int):
@@ -952,7 +967,7 @@ class GraphDEngine:
             def body(pg_, v, a, s):
                 nv, na, st = fn(sq(pg_), sq(v), sq(a), s)
                 return nv[None], na[None], st
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(spec, spec, spec, P()), out_specs=(spec, spec, P()),
             )
@@ -960,7 +975,7 @@ class GraphDEngine:
         def body(pg_):
             v, a = fn(sq(pg_))
             return v[None], a[None]
-        return shard_map(body, mesh=self.mesh, in_specs=(spec,),
+        return jax.shard_map(body, mesh=self.mesh, in_specs=(spec,),
                              out_specs=(spec, spec))
 
     def _wrap_kl(self, fn):
@@ -982,7 +997,7 @@ class GraphDEngine:
 
         # check_vma=False: pallas_call outputs carry no varying-mesh-axes
         # metadata, which the vma checker would otherwise reject.
-        return shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(spec, spec, spec, spec, P()),
             out_specs=(spec, spec, P()),
@@ -1005,7 +1020,7 @@ class GraphDEngine:
             nv, na, st, As, cn = fn(sq(pg_), sq(v), sq(a), s)
             return nv[None], na[None], st, As[None], cn[None]
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(spec, spec, spec, P()),
             out_specs=(spec, spec, P(), spec, spec),

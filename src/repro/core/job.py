@@ -62,6 +62,9 @@ class JobResult:
     realized_model: dict[str, int]
     realized_ram: int
     workdir: str
+    # where the supersteps ran: one {platform, kind} for a threads launch,
+    # one {shard, platform, kind} per worker for launch="processes"
+    devices: list[dict] = dataclasses.field(default_factory=list)
 
     @property
     def planned_ram(self) -> int:
@@ -97,6 +100,7 @@ class JobResult:
                                     for r in self.history),
                 blocks_skipped=sum(r.blocks_skipped for r in self.history),
             ),
+            devices=self.devices,
             history=[dataclasses.asdict(r) for r in self.history],
         )
 
@@ -172,6 +176,16 @@ class GraphDJob:
                 plan.config, channel=dataclasses.replace(
                     plan.config.channel, compress_payload="lossless"),
             ))
+        if launch == "processes":
+            # the workers take the chips, one each: the launcher stays on
+            # the host, and more shards than chips is refused before the
+            # partition is built
+            from repro.launch.placement import (
+                chips_for, keep_launcher_off_chip,
+            )
+
+            keep_launcher_off_chip()
+            chips_for(plan.n_shards)
         if plan.launch_opts:
             # plan-pinned deployment knobs are defaults; job args override
             self.launch_opts = {**plan.launch_opts, **self.launch_opts}
@@ -336,12 +350,16 @@ class GraphDJob:
                 (values, active), history = run_processes(
                     self, max_supersteps, verbose=verbose, on_step=on_step,
                 )
+                devices = self._last_run_devices
             else:
                 (values, active), history = self.engine.run(
                     max_supersteps=max_supersteps, state=self._state,
                     start_step=self._next_step, verbose=verbose,
                     checkpointer=self.checkpointer, on_step=on_step,
                 )
+                from repro.launch.placement import device_info
+
+                devices = [device_info()]
         finally:
             # success or failure, leave no half-written superstep scratch
             # (inbox runs, OMS spills, outbox/announce records) behind
@@ -357,6 +375,7 @@ class GraphDJob:
             realized_model=realized,
             realized_ram=ram_total(realized, self.plan.mode),
             workdir=self.workdir,
+            devices=devices,
         )
 
     def recover_shard(self, failed: int, target_step: int | None = None):

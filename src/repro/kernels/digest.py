@@ -5,7 +5,8 @@ pass, fused with the has-message count update — the receiver-side dual of
 edge_combine. Trivial compute, but it IS the U_r inner loop; as a Pallas
 kernel it streams both buffers HBM->VMEM in (1, WIN) tiles with the pipeline
 double-buffering the next tile during the combine (C3 overlap on the
-receive path)."""
+receive path). The buffers are viewed as (n, 1, WIN) so each block's last
+two dims equal the array's, which Mosaic accepts for any WIN."""
 
 from __future__ import annotations
 
@@ -35,17 +36,17 @@ def digest(A_r, cnt, recv, rcnt, *, combiner: str, WIN: int = 512,
     WIN = min(WIN, P)
     assert P % WIN == 0
     n = P // WIN
-    spec = pl.BlockSpec((1, WIN), lambda j: (j, 0))
+    spec = pl.BlockSpec((None, 1, WIN), lambda j: (j, 0, 0))
     kern = functools.partial(_kernel, combiner=combiner)
-    r2 = lambda x: x.reshape(n, WIN)
+    r2 = lambda x: x.reshape(n, 1, WIN)
     out, ocnt = pl.pallas_call(
         kern,
         grid=(n,),
         in_specs=[spec, spec, spec, spec],
         out_specs=[spec, spec],
         out_shape=[
-            jax.ShapeDtypeStruct((n, WIN), A_r.dtype),
-            jax.ShapeDtypeStruct((n, WIN), cnt.dtype),
+            jax.ShapeDtypeStruct((n, 1, WIN), A_r.dtype),
+            jax.ShapeDtypeStruct((n, 1, WIN), cnt.dtype),
         ],
         interpret=interpret,
     )(r2(A_r), r2(cnt), r2(recv), r2(rcnt))

@@ -30,8 +30,10 @@ Supported message kinds (trace-time specialization of compute(.)'s send):
   deg:     degree                      (neighbourhood degree sums)
 Combiners: sum (MXU matmul), min / max (VPU masked reduce).
 
-Layout notes (TPU tiling): the state table is (3, P) so the P axis rides the
-lanes; outputs are (n_dst_windows, DST_WIN) with (1, DST_WIN) blocks.
+Layout notes (TPU tiling): every operand carries a leading grid axis so the
+last two dims of each block equal the array's — the state table as
+(n_src_windows, 3, SRC_WIN), the edge channels as (NB, 1, BLK), the outputs
+as (n_dst_windows, 1, DST_WIN). Window positions ride the lanes in HBM.
 """
 
 from __future__ import annotations
@@ -93,33 +95,35 @@ def _kernel(
     msg_kind: str,
     combiner: str,
 ):
+    # Edges ride the lanes as (1, BLK) rows and window positions ride the
+    # sublanes, so every one-hot is a sublane broadcast of an edge row
+    # against a sublane iota (no 1-D vectors). Only min/max, which reduce
+    # over the lanes, transpose their (DST_WIN, 1) result.
     j = pl.program_id(0)
     blk = ids_ref[j]
     prev = ids_ref[jnp.maximum(j - 1, 0)]
     is_first = (j == 0) | (dwin_ref[blk] != dwin_ref[prev])
     live = j < nkeep_ref[0]
 
-    sp = sp_ref[0, :]
-    dp = dp_ref[0, :]
-    w = w_ref[0, :]
+    sp = sp_ref[...]
+    dp = dp_ref[...]
+    w = w_ref[...]
     src_base = swin_ref[blk] * SRC_WIN
     dst_base = dwin_ref[blk] * DST_WIN
 
     # --- one-hot gather of source state (MXU; Mosaic has no vector gather) ---
     sl = jnp.clip(sp - src_base, 0, SRC_WIN - 1)
     valid = (sp >= 0) & live
-    oh_s = jnp.where(
-        valid[:, None],
-        sl[:, None] == lax.broadcasted_iota(jnp.int32, (BLK, SRC_WIN), 1),
-        False,
-    )
-    # (BLK, SRC_WIN) x (3, SRC_WIN) -> (BLK, 3), contracting the window axis
+    oh_s = (lax.broadcasted_iota(jnp.int32, (SRC_WIN, BLK), 0) == sl) & valid
+    # (3, SRC_WIN) x (SRC_WIN, BLK) -> (3, BLK); HIGHEST keeps the f32
+    # values exact (a one-hot row selects, it must not round to bf16)
     g = lax.dot_general(
-        oh_s.astype(jnp.float32), state_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
+        state_ref[...], oh_s.astype(jnp.float32),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
-    vals, degs, acts = g[:, 0], g[:, 1], g[:, 2]
+    vals, degs, acts = g[0:1, :], g[1:2, :], g[2:3, :]
     aact = valid & (acts > 0.0)
 
     # --- compute(.)'s send, masked to the combiner identity ------------------
@@ -128,31 +132,33 @@ def _kernel(
 
     # --- one-hot combine into the A_s window (§5 in-memory combining) --------
     dl = jnp.clip(dp - dst_base, 0, DST_WIN - 1)
-    oh_d = jnp.where(
-        aact[:, None],
-        dl[:, None] == lax.broadcasted_iota(jnp.int32, (BLK, DST_WIN), 1),
-        False,
+    oh_d = (lax.broadcasted_iota(jnp.int32, (DST_WIN, BLK), 0) == dl) & aact
+    oh_df = oh_d.astype(jnp.float32)
+    # (1, BLK) x (DST_WIN, BLK) -> (1, DST_WIN), contracting the edge lanes
+    row_dot = lambda x: lax.dot_general(
+        x, oh_df, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     if combiner == "sum":
-        part = jnp.dot(msg, oh_d.astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
-    elif combiner == "min":
-        part = jnp.min(jnp.where(oh_d, msg[:, None], e0), axis=0)
+        part = row_dot(msg)
     else:
-        part = jnp.max(jnp.where(oh_d, msg[:, None], e0), axis=0)
-    cpart = jnp.dot(aact.astype(jnp.float32), oh_d.astype(jnp.float32),
-                    preferred_element_type=jnp.float32)
+        red = jnp.min if combiner == "min" else jnp.max
+        col = red(jnp.where(oh_d, msg, e0), axis=1, keepdims=True)
+        # (DST_WIN, 1) -> (1, DST_WIN): lane-broadcast then a 2-D transpose
+        part = jnp.transpose(jnp.broadcast_to(col, (DST_WIN, 128)))[0:1, :]
+    cpart = row_dot(aact.astype(jnp.float32))
 
     # --- window-run accumulation (first block initializes) -------------------
     @pl.when(is_first)
     def _init():
-        out_ref[0, :] = part
-        cnt_ref[0, :] = cpart
+        out_ref[...] = part
+        cnt_ref[...] = cpart
 
     @pl.when(jnp.logical_not(is_first))
     def _acc():
-        out_ref[0, :] = _combine2(combiner, out_ref[0, :], part)
-        cnt_ref[0, :] = cnt_ref[0, :] + cpart
+        out_ref[...] = _combine2(combiner, out_ref[...], part)
+        cnt_ref[...] = cnt_ref[...] + cpart
 
 
 def edge_combine_group(
@@ -175,22 +181,32 @@ def edge_combine_group(
     P = state3.shape[1]
     NB, BLK = sp.shape
     assert msg_kind in MSG_KINDS and combiner in COMBINERS
-    n_dwin = P // DST_WIN
+    n_swin, n_dwin = P // SRC_WIN, P // DST_WIN
 
+    # Every array gets a leading grid axis so each block's last two dims
+    # equal the array's (Mosaic's rule for blocks off the (8, 128) tiling).
+    state_w = state3.reshape(3, n_swin, SRC_WIN).transpose(1, 0, 2)
+    row = lambda x: x.reshape(NB, 1, BLK)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(NB,),
         in_specs=[
             pl.BlockSpec(
-                (3, SRC_WIN), lambda j, ids, nk, sw, dw: (0, sw[ids[j]])
+                (None, 3, SRC_WIN),
+                lambda j, ids, nk, sw, dw: (sw[ids[j]], 0, 0),
             ),
-            pl.BlockSpec((1, BLK), lambda j, ids, nk, sw, dw: (ids[j], 0)),
-            pl.BlockSpec((1, BLK), lambda j, ids, nk, sw, dw: (ids[j], 0)),
-            pl.BlockSpec((1, BLK), lambda j, ids, nk, sw, dw: (ids[j], 0)),
+            pl.BlockSpec((None, 1, BLK),
+                         lambda j, ids, nk, sw, dw: (ids[j], 0, 0)),
+            pl.BlockSpec((None, 1, BLK),
+                         lambda j, ids, nk, sw, dw: (ids[j], 0, 0)),
+            pl.BlockSpec((None, 1, BLK),
+                         lambda j, ids, nk, sw, dw: (ids[j], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, DST_WIN), lambda j, ids, nk, sw, dw: (dw[ids[j]], 0)),
-            pl.BlockSpec((1, DST_WIN), lambda j, ids, nk, sw, dw: (dw[ids[j]], 0)),
+            pl.BlockSpec((None, 1, DST_WIN),
+                         lambda j, ids, nk, sw, dw: (dw[ids[j]], 0, 0)),
+            pl.BlockSpec((None, 1, DST_WIN),
+                         lambda j, ids, nk, sw, dw: (dw[ids[j]], 0, 0)),
         ],
     )
     kernel = functools.partial(
@@ -198,8 +214,8 @@ def edge_combine_group(
         msg_kind=msg_kind, combiner=combiner,
     )
     out_shape = [
-        jax.ShapeDtypeStruct((n_dwin, DST_WIN), jnp.float32),
-        jax.ShapeDtypeStruct((n_dwin, DST_WIN), jnp.float32),
+        jax.ShapeDtypeStruct((n_dwin, 1, DST_WIN), jnp.float32),
+        jax.ShapeDtypeStruct((n_dwin, 1, DST_WIN), jnp.float32),
     ]
     A_s, cnt = pl.pallas_call(
         kernel,
@@ -211,9 +227,9 @@ def edge_combine_group(
         jnp.atleast_1d(n_keep).astype(jnp.int32),
         blk_swin.astype(jnp.int32),
         blk_dwin.astype(jnp.int32),
-        state3,
-        sp,
-        dp,
-        w,
+        state_w,
+        row(sp),
+        row(dp),
+        row(w),
     )
     return A_s.reshape(P), cnt.reshape(P)

@@ -1,22 +1,33 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` — the
-kernel body runs in Python/XLA exactly as written, which is how they are
-validated against ``ref.py``. On a TPU backend the same calls compile through
-Mosaic.
+Which form of a kernel runs is decided where the program is lowered, by
+the platform it is lowered for (``lax.platform_dependent``): for the CPU the
+kernel executes with ``interpret=True`` — the body runs in XLA exactly as
+written, which is how the tests validate it against ``ref.py`` — and for
+any other platform it compiles through Mosaic. So a program compiled for
+a TPU always holds the compiled kernel, even when the process that
+compiles it runs JAX on the CPU.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels import digest as _digest
 from repro.kernels import edge_combine as _ec
 
 
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+def _by_platform(kernel, *args, **kw):
+    """``kernel(*args, **kw)``, interpreted on the CPU, compiled elsewhere."""
+    return lax.platform_dependent(
+        *args,
+        cpu=functools.partial(kernel, interpret=True, **kw),
+        default=functools.partial(kernel, interpret=False, **kw),
+    )
 
 
 def window_first_mask(blk_dwin: jax.Array) -> jax.Array:
@@ -55,15 +66,15 @@ def edge_combine(
     state3, sp, dp, w, blk_ids, n_keep, blk_swin, blk_dwin,
     *, SRC_WIN, DST_WIN, msg_kind, combiner,
 ):
-    return _ec.edge_combine_group(
+    return _by_platform(
+        _ec.edge_combine_group,
         state3, sp, dp, w, blk_ids, n_keep, blk_swin, blk_dwin,
         SRC_WIN=SRC_WIN, DST_WIN=DST_WIN, msg_kind=msg_kind,
-        combiner=combiner, interpret=_interpret(),
+        combiner=combiner,
     )
 
 
 def digest(A_r, cnt, recv, rcnt, *, combiner, WIN: int = 512):
-    return _digest.digest(
-        A_r, cnt, recv, rcnt, combiner=combiner, WIN=WIN,
-        interpret=_interpret(),
+    return _by_platform(
+        _digest.digest, A_r, cnt, recv, rcnt, combiner=combiner, WIN=WIN,
     )

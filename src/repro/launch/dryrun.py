@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import cost_analysis, shard_map
 from repro.configs import ARCHS, SHAPES, cell_supported, get_config
 from repro.data.tokens import batch_specs
 from repro.launch.mesh import (
@@ -165,7 +164,7 @@ def lower_cell(arch: str, shape: str, mesh, cfg=None, opt_cfg=None,
 
 
 def _cost_of(compiled):
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes_from_text(compiled.as_text())
     return dict(
         flops=cost.get("flops", 0.0),
@@ -307,7 +306,7 @@ def run_graphd_cell(multi_pod: bool, scale: str = "clueweb",
         return nv[None], na[None], st
 
     spec = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         step, mesh=mesh,
         in_specs=(spec, spec, spec, P()),
         out_specs=(spec, spec, P()),
@@ -328,7 +327,7 @@ def run_graphd_cell(multi_pod: bool, scale: str = "clueweb",
     compiled = lowered.compile()
     t_compile = time.perf_counter() - t0
 
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     coll = collective_bytes_from_text(compiled.as_text())
     terms = roofline_terms(

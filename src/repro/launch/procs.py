@@ -76,6 +76,7 @@ import repro.fault as _fault
 from repro.core.coordinator import (
     FileCoordinator, RunAborted, WorkerFailed, atomic_write_json,
 )
+from repro.compile_cache import CACHE_ENV, cache_dir, use_compile_cache
 from repro.fault import (
     BlobCorruption,
     FaultEvent,
@@ -88,6 +89,7 @@ from repro.fault import (
     find_in_chain,
     write_record,
 )
+from repro.launch.placement import NO_DEVICE_EXIT, device_info, worker_envs
 
 SPEC = "spec.json"
 PROGRAM = "program.pkl"
@@ -167,6 +169,16 @@ def _src_root() -> str:
     return os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(core.__file__))
     ))
+
+
+def _child_env(src_root: str, place: dict) -> dict:
+    """A child's environment: the parent's, plus the import root, the
+    shared compile cache and the child's device placement."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
+    env[CACHE_ENV] = cache_dir()
+    env.update(place)
+    return env
 
 
 def _write_spec(job, procs_dir: str, coord_dir: str, *, start_step: int,
@@ -283,6 +295,9 @@ def run_processes(job, max_supersteps: int = 10_000, *,
             "'lossless' (or False) explicitly"
         )
     n = pg.n_shards
+    # one chip per worker, decided (and refused) before anything spawns
+    places = worker_envs(n)
+    job._last_run_devices = []  # each worker's {shard, platform, kind}
     from repro.core.config import validate_launch_opts
 
     opts = validate_launch_opts(dict(job.launch_opts or {}))
@@ -390,6 +405,7 @@ def run_processes(job, max_supersteps: int = 10_000, *,
                 coord_addr_path=_coord_addr_path(procs_dir))
     if transport == "sockets":
         return _run_sockets(job, opts, n=n, procs_dir=procs_dir,
+                            places=places,
                             start_step=start_step, target=target,
                             restored_from=restored_from,
                             can_recover=can_recover, verbose=verbose,
@@ -412,11 +428,10 @@ def run_processes(job, max_supersteps: int = 10_000, *,
                procs_dir, str(w)]
         if recover_to is not None:
             cmd += ["--recover-to", str(recover_to)]
-        env = dict(os.environ)
-        env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
         with open(os.path.join(d, "worker.log"), "ab") as logf:
-            procs[w] = subprocess.Popen(cmd, stdout=logf,
-                                        stderr=subprocess.STDOUT, env=env)
+            procs[w] = subprocess.Popen(
+                cmd, stdout=logf, stderr=subprocess.STDOUT,
+                env=_child_env(src_root, places[w]))
         # the parent's copy of the log fd is closed by the with-block; the
         # child holds its own.  Grace deadlines live on the monotonic
         # clock: an NTP step during spawn must not shrink (or stretch)
@@ -478,9 +493,10 @@ def run_processes(job, max_supersteps: int = 10_000, *,
                 silent = now > grace[w] and coord.stale(w)
                 if exited:
                     rec = _read_failure(procs_dir, w)
-                    _recover(w, step_or_none,
-                             _describe_exit(rec, p.returncode, step_or_none),
-                             record=rec)
+                    why = _describe_exit(rec, p.returncode, step_or_none)
+                    if p.returncode == NO_DEVICE_EXIT:
+                        _fail(w, f"worker {w} {why}", record=rec)
+                    _recover(w, step_or_none, why, record=rec)
                 elif silent:
                     _recover(w, step_or_none,
                              "went heartbeat-silent "
@@ -515,6 +531,7 @@ def run_processes(job, max_supersteps: int = 10_000, *,
                     p.kill()
             arrivals = coord.wait_arrivals(s, on_wait=_liveness(s))
             totals = coord.reduce_arrivals(arrivals)
+            job._last_run_devices = totals["devices"]
             for key in net_totals:
                 net_totals[key] += float(totals.get(key, 0.0))
             ckpt_landed = False
@@ -623,6 +640,8 @@ def _describe_exit(rec: dict | None, returncode, step) -> str:
         return f"exited with code {returncode}{at}"
     kind = rec.get("kind")
     msg = rec.get("message", "")
+    if kind == "no-device":
+        return f"could not open its device{at}: {msg}"
     if kind == "disk-fault":
         return (f"hit a disk fault in the {rec.get('tier', '?')} tier{at}: "
                 f"{msg}")
@@ -734,7 +753,7 @@ def _wal_last_commit(wal: str) -> int:
     return last
 
 
-def _run_sockets(job, opts, *, n, procs_dir, start_step, target,
+def _run_sockets(job, opts, *, n, procs_dir, places, start_step, target,
                  restored_from, can_recover, verbose, on_step):
     """Socket-transport launch: spawn the coordinator as its own process
     (:func:`coord_main`) plus one worker per shard, then supervise. The
@@ -761,19 +780,15 @@ def _run_sockets(job, opts, *, n, procs_dir, start_step, target,
     job._last_run_recoveries = 0
     job._last_run_coord_restarts = 0
 
-    def _env():
-        env = dict(os.environ)
-        env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
-        return env
-
     def _spawn_coord() -> None:
         nonlocal coord_proc
         cmd = [sys.executable, "-m", "repro.launch.procs", "coord",
                procs_dir, "--incarnation", str(incarnation)]
         with open(os.path.join(procs_dir, "coord.log"), "ab") as logf:
-            coord_proc = subprocess.Popen(cmd, stdout=logf,
-                                          stderr=subprocess.STDOUT,
-                                          env=_env())
+            # the coordinator is stdlib-only; keep it off the chips anyway
+            coord_proc = subprocess.Popen(
+                cmd, stdout=logf, stderr=subprocess.STDOUT,
+                env=_child_env(src_root, dict(JAX_PLATFORMS="cpu")))
 
     def _wait_addr() -> None:
         # trust only an address stamped with the CURRENT incarnation: a
@@ -806,9 +821,9 @@ def _run_sockets(job, opts, *, n, procs_dir, start_step, target,
         if recover_to is not None:
             cmd += ["--recover-to", str(recover_to)]
         with open(os.path.join(d, "worker.log"), "ab") as logf:
-            procs[w] = subprocess.Popen(cmd, stdout=logf,
-                                        stderr=subprocess.STDOUT,
-                                        env=_env())
+            procs[w] = subprocess.Popen(
+                cmd, stdout=logf, stderr=subprocess.STDOUT,
+                env=_child_env(src_root, places[w]))
 
     def _killall() -> None:
         victims = [p for p in procs + [coord_proc] if p is not None]
@@ -883,6 +898,7 @@ def _run_sockets(job, opts, *, n, procs_dir, start_step, target,
                 blocks_skipped=int(rec.get("blocks_skipped", 0)),
             )
             history.append(r)
+            job._last_run_devices = rec.get("devices", [])
             next_hist = s + 1
             for key in net_totals:
                 net_totals[key] += float(rec.get(key, 0.0))
@@ -969,10 +985,11 @@ def _run_sockets(job, opts, *, n, procs_dir, start_step, target,
                     procs[w] = None
                     continue
                 rec = _read_failure(procs_dir, w)
-                _respawn_worker(w, None,
-                                _describe_exit(rec, p.returncode,
-                                               _wal_last_commit(wal) + 1),
-                                record=rec)
+                why = _describe_exit(rec, p.returncode,
+                                     _wal_last_commit(wal) + 1)
+                if p.returncode == NO_DEVICE_EXIT:
+                    _abort_run(w, f"worker {w} {why}", record=rec)
+                _respawn_worker(w, None, why, record=rec)
             time.sleep(0.05)
         _drain_wal()
         vals, acts = [], []
@@ -1154,7 +1171,7 @@ class _Worker:
     PeerServer and a PeerSender transmit thread is wired to it)."""
 
     def __init__(self, spec: dict, program, shard: int, coord,
-                 server=None, peer_addrs=None):
+                 server=None, peer_addrs=None, device=None):
         import jax.numpy as jnp
 
         from repro.core.checkpoint import RunFileMessageLog
@@ -1168,6 +1185,7 @@ class _Worker:
         self.program = program
         self.w = shard
         self.coord = coord
+        self.device = device  # {platform, kind}: reported on every arrival
         self.n = int(spec["n_shards"])
         self.P = int(spec["P"])
         self.cfg = EngineConfig.from_json(spec["config"])
@@ -1731,6 +1749,7 @@ class _Worker:
                 active_blocks=int(nblocks), ckpt=ckpt,
                 blocks_read=m1 - m0, cache_hits=h1 - h0,
                 cache_evictions=e1 - e0, blocks_skipped=k1 - k0,
+                device=self.device,
             )
             if ns0 is not None:  # per-step socket channel accounting deltas
                 stats.update(
@@ -1837,12 +1856,22 @@ def worker_main(spec_dir: str, shard: int,
         coord.start_heartbeat(shard)
     wk = None
     try:
+        use_compile_cache()
+        try:
+            device = device_info()
+        except RuntimeError as e:  # the backend failed to initialise
+            print(f"worker {shard}: cannot open its device: {e}",
+                  file=sys.stderr)
+            write_record(_failure_path(spec["procs_dir"], int(shard)),
+                         failure_record("no-device", shard=int(shard),
+                                        message=str(e)))
+            return NO_DEVICE_EXIT
         if server is not None:
             peer_addrs = coord.register(server.addr)
         with open(os.path.join(spec_dir, PROGRAM), "rb") as f:
             program = pickle.load(f)
-        wk = _Worker(spec, program, shard, coord,
-                     server=server, peer_addrs=peer_addrs)
+        wk = _Worker(spec, program, shard, coord, server=server,
+                     peer_addrs=peer_addrs, device=device)
         wk.run(recover_to=recover_to)
         return 0
     except RunAborted as e:

@@ -156,7 +156,9 @@ def test_sharded_train_step_matches_single_device():
         ref_step = jax.jit(make_train_step(cfg, AdamWConfig(total_steps=10)))
         p1, o1, m1 = ref_step(params, opt, batch)
 
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
         ps = param_specs(params, mesh)
         os_ = dict(mu=ps, nu=ps, step=P())
         bs = batch_specs_tree(batch, mesh)
@@ -181,7 +183,6 @@ def test_graphd_dryrun_small_mesh():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from repro.compat import shard_map
         from repro.core.algorithms import PageRank
         from repro.core.engine import superstep_spmd
         from repro.graph.partition import abstract_partitioned_graph
@@ -199,7 +200,7 @@ def test_graphd_dryrun_small_mesh():
             return nv[None], na[None], st
 
         spec = P('machines')
-        fn = shard_map(step, mesh=mesh,
+        fn = jax.shard_map(step, mesh=mesh,
                        in_specs=(spec, spec, spec, P()),
                        out_specs=(spec, spec, P()))
         vals = jax.ShapeDtypeStruct((n, pg.P), jnp.float32)
@@ -210,8 +211,7 @@ def test_graphd_dryrun_small_mesh():
             fn, in_shardings=(jax.tree.map(lambda _: sh, pg), sh, sh,
                               NamedSharding(mesh, P())),
         ).lower(pg, vals, act, stp).compile()
-        from repro.compat import cost_analysis
-        cost = cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         assert cost.get('flops', 0) > 0
         print('OK', cost.get('flops'))
     """)

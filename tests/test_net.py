@@ -200,6 +200,12 @@ class TestCoordPlane:
             srv.abort("drill")
             with pytest.raises(RunAborted, match="drill"):
                 clients[0].wait_commit(1, 0)
+            # the abort is pushed to each client on its own connection:
+            # client 1's frame may land after client 0's
+            deadline = time.time() + 5
+            while clients[1].aborted() is None:
+                assert time.time() < deadline, "abort never reached client 1"
+                time.sleep(0.01)
             with pytest.raises(RunAborted, match="drill"):
                 clients[1].check_abort()
         finally:

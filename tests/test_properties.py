@@ -16,7 +16,7 @@ hypothesis = pytest.importorskip(
 import numpy as np
 from hypothesis import given, settings, strategies as st
 
-from repro.core import GraphDEngine, HashMin
+from repro.core import EngineConfig, GraphDEngine, HashMin
 from repro.core.api import IMAX, IMIN, MAX, MIN, OR, SUM
 from repro.graph import Graph, partition_graph, recode_ids
 from repro.graph.recode import recode_distributed
@@ -154,7 +154,7 @@ def test_property_modes_agree_on_random_graphs(edges, n):
     pg, _ = partition_graph(g, n_shards=n, edge_block=8)
     outs = []
     for mode in ["recoded", "basic", "basic_sc"]:
-        eng = GraphDEngine(pg, HashMin(), mode=mode)
+        eng = GraphDEngine(pg, HashMin(), config=EngineConfig(mode=mode))
         (vals, _), _ = eng.run()
         outs.append(eng.gather_values(vals))
     assert outs[0] == outs[1] == outs[2]
