@@ -61,7 +61,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 from typing import Any
 
@@ -71,6 +71,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import spans
 from repro.core.api import Combiner, ShardContext, VertexProgram
 from repro.core.config import MODES, ConfigError, EngineConfig
 from repro.graph.partition import PartitionedGraph
@@ -666,6 +667,10 @@ class SuperstepRecord:
     cache_hits: int = 0
     cache_evictions: int = 0
     blocks_skipped: int = 0
+    # streamed mode: this superstep's seconds by span name (core/spans.py).
+    # The compute thread's phases are disjoint parts of ``seconds``;
+    # "stream.stage" is the staging thread's reads, beside them
+    phase_seconds: dict[str, float] = field(default_factory=dict)
 
 
 class GraphDEngine:
@@ -1054,10 +1059,16 @@ class GraphDEngine:
         pending: list = []  # copied single-chunk groups awaiting one dispatch
         state = {"cur": None, "A": None, "cnt": None}
 
+        def emit(i, k, A, cnt, lane=None):
+            # after the fold's span has closed: phases do not nest
+            with spans.span("channel.send"):
+                if lane is not None:  # the group's lane of a batched fold
+                    A, cnt = A[lane], cnt[lane]
+                sink(i, k, A, cnt)
+
         def close_cur():
             if state["cur"] is not None:
-                sink(state["cur"][0], state["cur"][1], state["A"],
-                     state["cnt"])
+                emit(*state["cur"], state["A"], state["cnt"])
                 state["cur"] = None
 
         def flush_batch():
@@ -1065,24 +1076,28 @@ class GraphDEngine:
                 return
             if len(pending) == 1:
                 i, k, sp, dp, w = pending[0]
-                A_g, cnt_g = self._stream_fold(
-                    comb.identity((pg.P,), program.msg_dtype),
-                    jnp.zeros((pg.P,), jnp.int32),
-                    values[i], pg.degree[i], active[i],
-                    jnp.asarray(sp), jnp.asarray(dp), jnp.asarray(w), step,
-                )
-                sink(i, k, A_g, cnt_g)
+                with spans.span("stream.fold"):
+                    A_g, cnt_g = self._stream_fold(
+                        comb.identity((pg.P,), program.msg_dtype),
+                        jnp.zeros((pg.P,), jnp.int32),
+                        values[i], pg.degree[i], active[i],
+                        jnp.asarray(sp), jnp.asarray(dp), jnp.asarray(w),
+                        step,
+                    )
+                emit(i, k, A_g, cnt_g)
             else:
-                lanes = pending + [(0, -1) + pad] * (G - len(pending))
-                src = jnp.asarray(np.array([p[0] for p in lanes], np.int32))
-                sp = jnp.asarray(np.stack([p[2] for p in lanes]))
-                dp = jnp.asarray(np.stack([p[3] for p in lanes]))
-                w = jnp.asarray(np.stack([p[4] for p in lanes]))
-                A_b, cnt_b = self._stream_fold_batch(
-                    values, pg.degree, active, src, sp, dp, w, step
-                )
+                with spans.span("stream.fold"):
+                    lanes = pending + [(0, -1) + pad] * (G - len(pending))
+                    src = jnp.asarray(np.array([p[0] for p in lanes],
+                                               np.int32))
+                    sp = jnp.asarray(np.stack([p[2] for p in lanes]))
+                    dp = jnp.asarray(np.stack([p[3] for p in lanes]))
+                    w = jnp.asarray(np.stack([p[4] for p in lanes]))
+                    A_b, cnt_b = self._stream_fold_batch(
+                        values, pg.degree, active, src, sp, dp, w, step
+                    )
                 for g, (i, k, *_rest) in enumerate(pending):
-                    sink(i, k, A_b[g], cnt_b[g])
+                    emit(i, k, A_b, cnt_b, lane=g)
             pending.clear()
 
         for chunk in self._stream_reader.stream(schedule):
@@ -1092,8 +1107,9 @@ class GraphDEngine:
             if G > 1 and n_chunks[(i, k)] == 1:
                 # copy out of the reader's recycled staging buffers; the
                 # batch holds at most G chunks (modeled in the staging tier)
-                pending.append((i, k, np.array(chunk.sp), np.array(chunk.dp),
-                                np.array(chunk.w)))
+                with spans.span("stream.fold"):
+                    pending.append((i, k, np.array(chunk.sp),
+                                    np.array(chunk.dp), np.array(chunk.w)))
                 if len(pending) == G:
                     flush_batch()
                 continue
@@ -1102,17 +1118,18 @@ class GraphDEngine:
                 state["cur"] = (i, k)
                 state["A"] = comb.identity((pg.P,), program.msg_dtype)
                 state["cnt"] = jnp.zeros((pg.P,), jnp.int32)
-            state["A"], state["cnt"] = self._stream_fold(
-                state["A"], state["cnt"], values[i], pg.degree[i], active[i],
-                chunk.sp, chunk.dp, chunk.w, step,
-            )
-            # block before the reader recycles this chunk's buffer: on CPU
-            # jax the jitted fold may zero-copy ALIAS the staged numpy
-            # arrays, and dispatch is async — advancing the iterator would
-            # let the prefetch thread overwrite memory a pending computation
-            # still reads. Disk I/O still overlaps: the producer thread
-            # reads ahead while we wait on compute.
-            jax.block_until_ready(state["cnt"])
+            with spans.span("stream.fold"):
+                state["A"], state["cnt"] = self._stream_fold(
+                    state["A"], state["cnt"], values[i], pg.degree[i],
+                    active[i], chunk.sp, chunk.dp, chunk.w, step,
+                )
+                # block before the reader recycles this chunk's buffer: on
+                # CPU jax the jitted fold may zero-copy ALIAS the staged
+                # numpy arrays, and dispatch is async — advancing the
+                # iterator would let the prefetch thread overwrite memory a
+                # pending computation still reads. Disk I/O still overlaps:
+                # the producer thread reads ahead while we wait on compute.
+                jax.block_until_ready(state["cnt"])
         close_cur()
         flush_batch()
 
@@ -1135,17 +1152,19 @@ class GraphDEngine:
         if log is None:
             for chunk in reader.stream(schedule):
                 i, k = chunk.src_shard, chunk.dst_shard
-                A_r[k], cnt[k] = self._stream_fold(
-                    A_r[k], cnt[k], values[i], pg.degree[i], active[i],
-                    chunk.sp, chunk.dp, chunk.w, step,
-                )
-                # block before the reader recycles this chunk's buffer: on
-                # CPU jax the jitted fold may zero-copy ALIAS the staged
-                # numpy arrays, and dispatch is async — advancing the
-                # iterator would let the prefetch thread overwrite memory a
-                # pending computation still reads. Disk I/O still overlaps:
-                # the producer thread reads ahead while we wait on compute.
-                jax.block_until_ready(cnt[k])
+                with spans.span("stream.fold"):
+                    A_r[k], cnt[k] = self._stream_fold(
+                        A_r[k], cnt[k], values[i], pg.degree[i], active[i],
+                        chunk.sp, chunk.dp, chunk.w, step,
+                    )
+                    # block before the reader recycles this chunk's buffer:
+                    # on CPU jax the jitted fold may zero-copy ALIAS the
+                    # staged numpy arrays, and dispatch is async — advancing
+                    # the iterator would let the prefetch thread overwrite
+                    # memory a pending computation still reads. Disk I/O
+                    # still overlaps: the producer thread reads ahead while
+                    # we wait on compute.
+                    jax.block_until_ready(cnt[k])
         else:
             # create the step's run store up front: even an all-skipped
             # superstep must publish an (empty) index or recovery of that
@@ -1163,16 +1182,17 @@ class GraphDEngine:
         n_active = n_msgs = 0
         agg = 0.0
         for k in range(n):
-            nv, na, nact, nm, ag = self._stream_apply(
-                values[k], pg.degree[k], pg.vmask[k], pg.old_ids[k],
-                pg.gids[k], A_r[k], cnt[k], active[k], step,
-                jnp.int32(k),
-            )
-            new_v.append(nv)
-            new_a.append(na)
-            n_active += int(nact)
-            n_msgs += int(nm)
-            agg += float(ag)
+            with spans.span("stream.apply"):
+                nv, na, nact, nm, ag = self._stream_apply(
+                    values[k], pg.degree[k], pg.vmask[k], pg.old_ids[k],
+                    pg.gids[k], A_r[k], cnt[k], active[k], step,
+                    jnp.int32(k),
+                )
+                new_v.append(nv)
+                new_a.append(na)
+                n_active += int(nact)
+                n_msgs += int(nm)
+                agg += float(ag)
         st = reader.stats
         io_note = f"{st.blocks_read}blk/{st.bytes_read >> 10}KiB"
         return (jnp.stack(new_v), jnp.stack(new_a), n_active, n_msgs, agg,
@@ -1330,22 +1350,25 @@ class GraphDEngine:
                     A_r, cnt = receiver.collect(k)
                 else:
                     # half-duplex: digest inline, in transmit order
-                    A_r = comb.identity((pg.P,), program.msg_dtype)
-                    cnt = jnp.zeros((pg.P,), jnp.int32)
-                    for seg in inbox.runs(k):
-                        A_d, c_d = inbox.read_combined(k, seg, comb.e0)
-                        A_r, cnt = self._stream_digest(
-                            A_r, cnt, jnp.asarray(A_d), jnp.asarray(c_d)
-                        )
-                nv, na, nact, nm, ag = self._stream_apply(
-                    values[k], pg.degree[k], pg.vmask[k], pg.old_ids[k],
-                    pg.gids[k], A_r, cnt, active[k], step, jnp.int32(k),
-                )
-                new_v.append(nv)
-                new_a.append(na)
-                n_active += int(nact)
-                n_msgs += int(nm)
-                agg += float(ag)
+                    with spans.span("channel.digest"):
+                        A_r = comb.identity((pg.P,), program.msg_dtype)
+                        cnt = jnp.zeros((pg.P,), jnp.int32)
+                        for seg in inbox.runs(k):
+                            A_d, c_d = inbox.read_combined(k, seg, comb.e0)
+                            A_r, cnt = self._stream_digest(
+                                A_r, cnt, jnp.asarray(A_d), jnp.asarray(c_d)
+                            )
+                with spans.span("stream.apply"):
+                    nv, na, nact, nm, ag = self._stream_apply(
+                        values[k], pg.degree[k], pg.vmask[k],
+                        pg.old_ids[k], pg.gids[k], A_r, cnt, active[k],
+                        step, jnp.int32(k),
+                    )
+                    new_v.append(nv)
+                    new_a.append(na)
+                    n_active += int(nact)
+                    n_msgs += int(nm)
+                    agg += float(ag)
             channel.close()  # surface a late sender error before publishing
             if receiver is not None:
                 receiver.close()
@@ -1484,27 +1507,31 @@ class GraphDEngine:
                     if cur_src is not None and i != cur_src:
                         # keep the merge fan-in bounded: collapse the finished
                         # source's runs down to one (multi-pass §3.3.1)
+                        with spans.span("channel.send"):
+                            compact(k, cur_src, self.msg_merge_fanin,
+                                    self.msg_read_chunk)
+                    cur_src = i
+                    with spans.span("stream.fold"):
+                        msg, dp, valid = self._stream_msgs(
+                            values[i], pg.degree[i], active[i],
+                            chunk.sp, chunk.dp, chunk.w, step,
+                        )
+                        # np.asarray both blocks on the async result and
+                        # copies out of the reader's recycled staging buffers
+                        msg = np.asarray(msg)
+                        dp = np.asarray(dp)
+                        valid = np.asarray(valid)
+                    with spans.span("channel.send"):
+                        if channel is not None:
+                            # sort + append move to the sender thread; the
+                            # next chunk's message generation overlaps them
+                            channel.send_raw(k, dp, msg, valid, tag=i)
+                        else:
+                            mstore.append_raw(k, dp, msg, valid, tag=i)
+                if cur_src is not None:
+                    with spans.span("channel.send"):
                         compact(k, cur_src, self.msg_merge_fanin,
                                 self.msg_read_chunk)
-                    cur_src = i
-                    msg, dp, valid = self._stream_msgs(
-                        values[i], pg.degree[i], active[i],
-                        chunk.sp, chunk.dp, chunk.w, step,
-                    )
-                    # np.asarray both blocks on the async result and copies
-                    # out of the reader's recycled staging buffers
-                    msg = np.asarray(msg)
-                    dp = np.asarray(dp)
-                    valid = np.asarray(valid)
-                    if channel is not None:
-                        # sort + append move to the sender thread; the next
-                        # chunk's message generation overlaps them
-                        channel.send_raw(k, dp, msg, valid, tag=i)
-                    else:
-                        mstore.append_raw(k, dp, msg, valid, tag=i)
-                if cur_src is not None:
-                    compact(k, cur_src, self.msg_merge_fanin,
-                            self.msg_read_chunk)
                 blocks += reader.stats.blocks_read
                 kib += reader.stats.bytes_read >> 10
                 if channel is not None:
@@ -1512,17 +1539,19 @@ class GraphDEngine:
 
                 # -- merge + apply (shared with recovery); with a channel
                 # the merge runs on the accounted receiver thread (U_r)
-                acc_v, acc_a, cnt_k = self._apply_list_merged(
-                    mstore, k, values[k], active[k], step, channel=channel
-                )
-                nact, nm, ag = self._stream_finish(
-                    values[k], acc_v, acc_a, cnt_k, pg.vmask[k]
-                )
-                new_v.append(acc_v)
-                new_a.append(acc_a)
-                n_active += int(nact)
-                n_msgs += int(nm)
-                agg += float(ag)
+                with spans.span("stream.apply"):
+                    acc_v, acc_a, cnt_k = self._apply_list_merged(
+                        mstore, k, values[k], active[k], step,
+                        channel=channel
+                    )
+                    nact, nm, ag = self._stream_finish(
+                        values[k], acc_v, acc_a, cnt_k, pg.vmask[k]
+                    )
+                    new_v.append(acc_v)
+                    new_a.append(acc_a)
+                    n_active += int(nact)
+                    n_msgs += int(nm)
+                    agg += float(ag)
                 if log is None:
                     mstore.clear_dest(k)  # applied => this OMS is dead (§3.3)
             if channel is not None:
@@ -1608,30 +1637,35 @@ class GraphDEngine:
         )
         residency = self._residency
         nonempty_total = store.nonempty_blocks()
+        if comb is None:
+            superstep = self._superstep_streamed_nocomb
+        elif self.pipeline:
+            superstep = self._superstep_streamed_comb_pipelined
+        else:
+            superstep = self._superstep_streamed_comb
         for s in range(start_step, target):
+            phases: dict[str, float] = {}
             t0 = time.perf_counter()
-            if comb is None:
-                superstep = self._superstep_streamed_nocomb
-            elif self.pipeline:
-                superstep = self._superstep_streamed_comb_pipelined
-            else:
-                superstep = self._superstep_streamed_comb
-            # selective scheduling: everything skip() left off this step's
-            # plan is disk I/O that never happens — tally it before the
-            # step so the record's counters describe THIS superstep
-            scheduled = sum(
-                len(ids) for per_dest in plan for _, _, ids in per_dest
-            )
-            residency.note_skipped(nonempty_total - scheduled)
-            hits0, miss0, evict0, _ = residency.counters()
-            values, active, n_active, n_msgs, agg, io_note = superstep(
-                values, active, s, plan
-            )
-            hits1, miss1, evict1, _ = residency.counters()
-            self._decide_payload_codec()  # no-op unless "auto" undecided
-            plan, density, max_grp = plan_stream_schedule(
-                store, np.asarray(active), by_dest=True
-            )
+            with spans.collect(phases):
+                with spans.span("stream.schedule"):
+                    # selective scheduling: everything skip() left off this
+                    # step's plan is disk I/O that never happens — tally it
+                    # before the step so the record's counters describe
+                    # THIS superstep
+                    scheduled = sum(
+                        len(ids) for per_dest in plan for _, _, ids in per_dest
+                    )
+                    residency.note_skipped(nonempty_total - scheduled)
+                    hits0, miss0, evict0, _ = residency.counters()
+                values, active, n_active, n_msgs, agg, io_note = superstep(
+                    values, active, s, plan
+                )
+                with spans.span("stream.schedule"):
+                    hits1, miss1, evict1, _ = residency.counters()
+                    self._decide_payload_codec()  # no-op unless "auto"
+                    plan, density, max_grp = plan_stream_schedule(
+                        store, np.asarray(active), by_dest=True
+                    )
             dt = time.perf_counter() - t0
             rec = SuperstepRecord(
                 step=s, n_active=n_active, n_msgs=n_msgs, agg=agg,
@@ -1640,6 +1674,7 @@ class GraphDEngine:
                 blocks_read=miss1 - miss0, cache_hits=hits1 - hits0,
                 cache_evictions=evict1 - evict0,
                 blocks_skipped=nonempty_total - scheduled,
+                phase_seconds=phases,
             )
             history.append(rec)
             if verbose:

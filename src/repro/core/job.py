@@ -39,6 +39,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.checkpoint import (
     Checkpointer, MessageLog, RunFileMessageLog, recover_shard,
     recover_shard_streamed,
@@ -65,6 +66,8 @@ class JobResult:
     # where the supersteps ran: one {platform, kind} for a threads launch,
     # one {shard, platform, kind} per worker for launch="processes"
     devices: list[dict] = dataclasses.field(default_factory=list)
+    # the job's set-up seconds by span name (GraphDJob.setup_seconds)
+    setup: dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
     def planned_ram(self) -> int:
@@ -101,6 +104,7 @@ class JobResult:
                 blocks_skipped=sum(r.blocks_skipped for r in self.history),
             ),
             devices=self.devices,
+            setup=self.setup,
             history=[dataclasses.asdict(r) for r in self.history],
         )
 
@@ -154,10 +158,13 @@ class GraphDJob:
         # expert plans are materialized verbatim; only budget-derived plans
         # get their knobs re-derived against the realized geometry
         self._auto_planned = plan is None
+        # set-up seconds by span: job.plan, partition.*, job.engine
+        self.setup_seconds: dict[str, float] = {}
         if plan is None:
-            plan = make_plan(program, GraphMeta.of(graph), budget,
-                             edge_block=edge_block, vertex_pad=vertex_pad,
-                             launch=launch)
+            with spans.collect(self.setup_seconds), spans.span("job.plan"):
+                plan = make_plan(program, GraphMeta.of(graph), budget,
+                                 edge_block=edge_block,
+                                 vertex_pad=vertex_pad, launch=launch)
         elif launch == "processes" and plan.mode != "streamed":
             raise ValueError(
                 "launch='processes' needs a mode='streamed' plan (workers "
@@ -213,7 +220,8 @@ class GraphDJob:
         self._next_step = 0
         self._closed = False
         try:
-            self._build(tag="")
+            with spans.collect(self.setup_seconds):
+                self._build(tag="")
         except BaseException:
             # a failure between partition-spill and engine wiring must not
             # strand the workdir the job itself created: mark the job closed
@@ -263,7 +271,13 @@ class GraphDJob:
         self.pg, self.rmap, self.store = partition_for_plan(
             self.graph, plan, self._dir("edges", tag)
         )
-        plan = self.plan = self._refine_plan(plan)
+        with spans.span("job.plan"):
+            plan = self.plan = self._refine_plan(plan)
+        with spans.span("job.engine"):
+            self._wire(plan, tag)
+
+    def _wire(self, plan: ExecutionPlan, tag: str) -> None:
+        """Checkpointer, message log and engine for the built partition."""
         rec = plan.config.recovery
         self.checkpointer = (
             Checkpointer(self._dir("ckpt", tag), every=rec.checkpoint_every,
@@ -376,6 +390,7 @@ class GraphDJob:
             realized_ram=ram_total(realized, self.plan.mode),
             workdir=self.workdir,
             devices=devices,
+            setup=dict(self.setup_seconds),
         )
 
     def recover_shard(self, failed: int, target_step: int | None = None):

@@ -26,6 +26,7 @@ import jax
 import numpy as np
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.graph.csr import Graph
 from repro.graph.recode import RecodeMap, recode_ids
 
@@ -98,6 +99,24 @@ def build_partition(
     Global ids obey shard = g mod n, pos = g // n for ANY n — this is what
     makes elastic repartitioning (core/elastic.py) a pure index transform.
     """
+    with spans.span("partition.build"):
+        geom, host = _host_layout(n, src_g, dst_g, weight, gids_real,
+                                  old_ids_real, edge_block, vertex_pad)
+    with spans.span("partition.upload"):
+        return PartitionedGraph(
+            n_shards=n,
+            n_vertices=int(gids_real.shape[0]),
+            n_edges=int(src_g.shape[0]),
+            edge_block=edge_block,
+            **geom,
+            **{k: jnp.asarray(v) for k, v in host.items()},
+        )
+
+
+def _host_layout(n, src_g, dst_g, weight, gids_real, old_ids_real,
+                 edge_block, vertex_pad):
+    """:func:`build_partition`'s arrays on the host: the geometry
+    (``P``, ``E_cap``, ``n_blocks``) and the arrays by field name."""
     P = max(_round_up(int(gids_real.max()) // n + 1 if gids_real.size else 1,
                       vertex_pad), vertex_pad)
     src_shard, src_p = src_g % n, src_g // n
@@ -143,23 +162,10 @@ def build_partition(
     old_ids[gids_real % n, gids_real // n] = old_ids_real
     gid_arr[gids_real % n, gids_real // n] = gids_real
 
-    return PartitionedGraph(
-        n_shards=n,
-        n_vertices=int(gids_real.shape[0]),
-        n_edges=int(src_g.shape[0]),
-        P=P,
-        E_cap=E_cap,
-        edge_block=edge_block,
-        n_blocks=n_blocks,
-        degree=jnp.asarray(degree),
-        vmask=jnp.asarray(vmask),
-        old_ids=jnp.asarray(old_ids),
-        gids=jnp.asarray(gid_arr),
-        src_pos=jnp.asarray(src_pos),
-        dst_pos=jnp.asarray(dst_pos),
-        eweight=jnp.asarray(eweight),
-        blk_lo=jnp.asarray(blk_lo),
-        blk_hi=jnp.asarray(blk_hi),
+    return dict(P=P, E_cap=E_cap, n_blocks=n_blocks), dict(
+        degree=degree, vmask=vmask, old_ids=old_ids, gids=gid_arr,
+        src_pos=src_pos, dst_pos=dst_pos, eweight=eweight,
+        blk_lo=blk_lo, blk_hi=blk_hi,
     )
 
 
@@ -171,11 +177,14 @@ def partition_graph(
     recode: RecodeMap | None = None,
 ) -> tuple[PartitionedGraph, RecodeMap]:
     """Preprocess (host-side, the paper's loading + ID-recoding pass)."""
-    rmap = recode if recode is not None else recode_ids(g.vertex_ids, n_shards)
+    with spans.span("partition.recode"):
+        rmap = (recode if recode is not None
+                else recode_ids(g.vertex_ids, n_shards))
+        src, dst = rmap.to_new(g.src), rmap.to_new(g.dst)
     pg = build_partition(
         n_shards,
-        rmap.to_new(g.src),
-        rmap.to_new(g.dst),
+        src,
+        dst,
         g.weight,
         rmap.new_for_old_sorted,
         rmap.old_sorted,
@@ -215,10 +224,12 @@ def spill_partition(pg: PartitionedGraph, directory: str,
     the weight channel (both streams/codec.py, both lossless)."""
     from repro.streams.store import EdgeStreamStore  # deferred: streams -> partition
 
-    store = EdgeStreamStore.from_partition(
-        pg, directory, compress=compress, compress_payload=compress_payload,
-    )
-    return drop_edges(pg), store
+    with spans.span("partition.spill"):
+        store = EdgeStreamStore.from_partition(
+            pg, directory, compress=compress,
+            compress_payload=compress_payload,
+        )
+        return drop_edges(pg), store
 
 
 def partition_graph_streamed(

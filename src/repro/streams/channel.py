@@ -56,6 +56,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.core import spans
 from repro.streams.msgstore import MessageRunStore
 
 
@@ -100,6 +101,9 @@ class ChannelStats:
     messages: int = 0
     payload_bytes: int = 0  # pre-serialization bytes handed to the sender
     wire_bytes: int = 0  # bytes actually appended to the inbox files
+    # the seconds of spans (core/spans.py): "channel.transmit" on the
+    # sender, "channel.digest" on the receiver, "channel.barrier" for the
+    # compute thread's waits in flush() and collect()
     send_seconds: float = 0.0  # sender busy (serialize/compress/append)
     stall_seconds: float = 0.0  # compute thread blocked on the channel
     recv_runs: int = 0  # inbox runs digested by the background receiver
@@ -240,12 +244,15 @@ class ShardChannels:
         if the sender died first — a barrier released by the death-path
         drain does NOT mean the ops before it landed."""
         done = threading.Event()
-        self._put(("barrier", done))
-        t0 = time.perf_counter()
-        while not done.wait(timeout=0.05):
-            if self._dead.is_set():
-                break
-        self.stats.stall_seconds += time.perf_counter() - t0
+        barrier = spans.span("channel.barrier")
+        try:
+            with barrier:
+                self._enqueue(("barrier", done))
+                while not done.wait(timeout=0.05):
+                    if self._dead.is_set():
+                        break
+        finally:
+            self.stats.stall_seconds += barrier.seconds
         if self._dead.is_set():
             # the sender processes ops FIFO and this thread is the only
             # producer, so a dead sender at this point means the barrier was
@@ -304,17 +311,21 @@ class ShardChannels:
 
     def _put(self, item) -> None:
         t0 = time.perf_counter()
+        try:
+            self._enqueue(item)
+        finally:
+            self.stats.stall_seconds += time.perf_counter() - t0
+
+    def _enqueue(self, item) -> None:
         while True:
             if self._dead.is_set():
-                self.stats.stall_seconds += time.perf_counter() - t0
                 self._raise()
                 raise ChannelError("channel is closed")
             try:
                 self._q.put(item, timeout=0.05)
-                break
+                return
             except queue.Full:
                 pass
-        self.stats.stall_seconds += time.perf_counter() - t0
 
     def _run(self) -> None:
         try:
@@ -326,31 +337,34 @@ class ShardChannels:
                 if op == "barrier":
                     item[1].set()
                     continue
-                t0 = time.perf_counter()
-                if op == "run":
-                    _, dest, dp, msg, cnt, tag = item
-                    seg = self.inbox.append_run(dest, dp, msg, cnt=cnt,
-                                                tag=tag)
-                    self._account(dp, msg, cnt, seg)
-                    self._notify_receiver(dest, seg)
-                elif op == "combined":
-                    _, dest, A, cnt, tag = item
-                    seg = self.inbox.append_combined(dest, A, cnt, tag=tag)
-                    self._account_n(seg.length,
-                                    seg.length * (4 + A.itemsize + 4), seg)
-                    self._notify_receiver(dest, seg)
-                elif op == "raw":
-                    _, dest, dp, msg, valid, tag = item
-                    seg = self.inbox.append_raw(dest, dp, msg, valid, tag=tag)
-                    n = seg.length if seg is not None else 0
-                    per = dp.itemsize + msg.itemsize
-                    self._account_n(n, n * per, seg)
-                elif op == "compact":
-                    _, dest, tag, fanin, read_chunk = item
-                    self.inbox.compact_tag(dest, tag, fanin, read_chunk)
-                    self.stats.send_seconds += time.perf_counter() - t0
+                with spans.span("channel.transmit") as sent:
+                    if op == "run":
+                        _, dest, dp, msg, cnt, tag = item
+                        seg = self.inbox.append_run(dest, dp, msg, cnt=cnt,
+                                                    tag=tag)
+                        self._account(dp, msg, cnt, seg)
+                        self._notify_receiver(dest, seg)
+                    elif op == "combined":
+                        _, dest, A, cnt, tag = item
+                        seg = self.inbox.append_combined(dest, A, cnt,
+                                                         tag=tag)
+                        self._account_n(seg.length,
+                                        seg.length * (4 + A.itemsize + 4),
+                                        seg)
+                        self._notify_receiver(dest, seg)
+                    elif op == "raw":
+                        _, dest, dp, msg, valid, tag = item
+                        seg = self.inbox.append_raw(dest, dp, msg, valid,
+                                                    tag=tag)
+                        n = seg.length if seg is not None else 0
+                        per = dp.itemsize + msg.itemsize
+                        self._account_n(n, n * per, seg)
+                    elif op == "compact":
+                        _, dest, tag, fanin, read_chunk = item
+                        self.inbox.compact_tag(dest, tag, fanin, read_chunk)
+                self.stats.send_seconds += sent.seconds
+                if op == "compact":
                     continue
-                self.stats.send_seconds += time.perf_counter() - t0
                 if self._fault is not None:
                     self._fault.record()
         except BaseException as e:
@@ -461,12 +475,12 @@ class ChannelReceiver:
         box: list = [None]
         done = threading.Event()
         self._q.put(("collect", dest, box, done))
-        t0 = time.perf_counter()
-        while not done.wait(timeout=0.05):
-            if self._dead.is_set():
-                break
+        with spans.span("channel.barrier") as barrier:
+            while not done.wait(timeout=0.05):
+                if self._dead.is_set():
+                    break
         if self.stats is not None:
-            self.stats.recv_stall_seconds += time.perf_counter() - t0
+            self.stats.recv_stall_seconds += barrier.seconds
         if self._dead.is_set() and not done.is_set():
             self._raise()
             raise ChannelError("channel receiver died before the collect")
@@ -516,14 +530,15 @@ class ChannelReceiver:
                     done.set()
                     continue
                 _, dest, seg = item
-                t0 = time.perf_counter()
-                A_d, c_d = self.inbox.read_combined(dest, seg, self._e0)
-                acc = self._acc.get(dest)
-                if acc is None:
-                    acc = self._identity()
-                self._acc[dest] = self._digest(acc[0], acc[1], A_d, c_d)
+                with spans.span("channel.digest") as digested:
+                    A_d, c_d = self.inbox.read_combined(dest, seg, self._e0)
+                    acc = self._acc.get(dest)
+                    if acc is None:
+                        acc = self._identity()
+                    self._acc[dest] = self._digest(acc[0], acc[1], A_d,
+                                                   c_d)
                 if self.stats is not None:
-                    self.stats.recv_seconds += time.perf_counter() - t0
+                    self.stats.recv_seconds += digested.seconds
                     self.stats.recv_runs += 1
                 if self._fault is not None:
                     self._fault.record()

@@ -26,6 +26,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.core import spans
 from repro.streams.store import EdgeStreamStore
 
 
@@ -50,8 +51,10 @@ class StreamStats:
     blocks_read: int = 0
     edges_staged: int = 0
     bytes_read: int = 0
-    read_seconds: float = 0.0  # producer time spent filling buffers
-    wait_seconds: float = 0.0  # consumer time spent blocked on the producer
+    # producer time spent filling buffers (span "stream.stage")
+    read_seconds: float = 0.0
+    # consumer time spent blocked on the producer (span "stream.stage_wait")
+    wait_seconds: float = 0.0
 
     def throughput_edges_per_s(self) -> float:
         return self.edges_staged / self.read_seconds if self.read_seconds else 0.0
@@ -240,18 +243,18 @@ class StreamReader:
                         if stop.is_set():
                             return
                         sp, dp, w = pool[bid]
-                        t0 = time.perf_counter()
-                        if self.residency is not None:
-                            c, disk = self.residency.read_blocks(
-                                self._reader_for(i), i, k,
-                                ids[off:off + CB], sp, dp, w
-                            )
-                        else:
-                            c = self._reader_for(i).read_blocks(
-                                i, k, ids[off:off + CB], sp, dp, w
-                            )
-                            disk = c
-                        stats.read_seconds += time.perf_counter() - t0
+                        with spans.span("stream.stage") as staged:
+                            if self.residency is not None:
+                                c, disk = self.residency.read_blocks(
+                                    self._reader_for(i), i, k,
+                                    ids[off:off + CB], sp, dp, w
+                                )
+                            else:
+                                c = self._reader_for(i).read_blocks(
+                                    i, k, ids[off:off + CB], sp, dp, w
+                                )
+                                disk = c
+                        stats.read_seconds += staged.seconds
                         stats.chunks += 1
                         stats.blocks_read += disk
                         stats.bytes_read += disk * B * 12  # i32+i32+f32/edge
@@ -273,9 +276,9 @@ class StreamReader:
         held: StagedChunk | None = None
         try:
             while True:
-                t0 = time.perf_counter()
-                item = full.get()
-                stats.wait_seconds += time.perf_counter() - t0
+                with spans.span("stream.stage_wait") as waited:
+                    item = full.get()
+                stats.wait_seconds += waited.seconds
                 # the consumer has moved past the previous chunk — its buffer
                 # can be refilled. The consumer MUST have finished reading it
                 # (jnp may alias, not copy, these arrays on CPU; the engine
@@ -294,6 +297,8 @@ class StreamReader:
             # unblock a producer waiting on a free buffer, then drain
             free.put(0)
             worker.join(timeout=5.0)
+            # the staging thread's seconds count in the consumer's phases
+            spans.add("stream.stage", stats.read_seconds)
             if worker.is_alive() and sys.exc_info()[1] is None:
                 # same leak guard as prefetch_iter: a staging thread that
                 # outlives its pass keeps store FDs (and mmap views) open
