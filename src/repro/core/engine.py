@@ -113,6 +113,20 @@ def _block_active(pg: PartitionedGraph, prefix: jax.Array, lo, hi) -> jax.Array:
 # combine them into A_s. Dense, sparse (skip) and sort (merge-sort) variants.
 # --------------------------------------------------------------------------
 
+def _source_rows(values, degree, active):
+    """``rows(i)``: source shard i's (values, degree, active) rows, sliced
+    at the first call for i. A row slice is two op-by-op dispatches, which
+    a streamed pass would otherwise pay for every chunk of the source."""
+    cache: dict[int, tuple] = {}
+
+    def rows(i):
+        if i not in cache:
+            cache[i] = (values[i], degree[i], active[i])
+        return cache[i]
+
+    return rows
+
+
 def _gen_messages(program, values, degree, sp, dp, w, active, step):
     """Gather source state, evaluate program.message, mask invalid/inactive."""
     spc = jnp.clip(sp, 0)
@@ -667,6 +681,11 @@ class SuperstepRecord:
     cache_hits: int = 0
     cache_evictions: int = 0
     blocks_skipped: int = 0
+    # streamed mode: staging-buffer refills that waited for the device to
+    # finish reading the buffer (StreamStats.recycle_waits), and the
+    # staging thread's seconds spent so
+    recycle_waits: int = 0
+    recycle_wait_seconds: float = 0.0
     # streamed mode: this superstep's seconds by span name (core/spans.py).
     # The compute thread's phases are disjoint parts of ``seconds``;
     # "stream.stage" is the staging thread's reads, beside them
@@ -1040,7 +1059,10 @@ class GraphDEngine:
         and the pipelined superstep (sink: channel transmit) — the group
         keying, identity re-init and buffer-recycle contract live in
         exactly one place, so the two paths' bit-identical-grouping
-        guarantee cannot drift.
+        guarantee cannot drift. A chunk's fold is dispatched and left
+        running: its result is the chunk's release token, so the reader
+        refills the staging buffer once the device has read it, and the
+        only wait is the sink's, once a group.
 
         Small groups (a single staged chunk) are folded ``group_batch`` at
         a time through one padded vmapped dispatch — per lane the same ops
@@ -1058,6 +1080,7 @@ class GraphDEngine:
                np.zeros((slots,), np.float32))
         pending: list = []  # copied single-chunk groups awaiting one dispatch
         state = {"cur": None, "A": None, "cnt": None}
+        rows = _source_rows(values, pg.degree, active)
 
         def emit(i, k, A, cnt, lane=None):
             # after the fold's span has closed: phases do not nest
@@ -1079,8 +1102,7 @@ class GraphDEngine:
                 with spans.span("stream.fold"):
                     A_g, cnt_g = self._stream_fold(
                         comb.identity((pg.P,), program.msg_dtype),
-                        jnp.zeros((pg.P,), jnp.int32),
-                        values[i], pg.degree[i], active[i],
+                        jnp.zeros((pg.P,), jnp.int32), *rows(i),
                         jnp.asarray(sp), jnp.asarray(dp), jnp.asarray(w),
                         step,
                     )
@@ -1120,16 +1142,13 @@ class GraphDEngine:
                 state["cnt"] = jnp.zeros((pg.P,), jnp.int32)
             with spans.span("stream.fold"):
                 state["A"], state["cnt"] = self._stream_fold(
-                    state["A"], state["cnt"], values[i], pg.degree[i],
-                    active[i], chunk.sp, chunk.dp, chunk.w, step,
+                    state["A"], state["cnt"], *rows(i),
+                    chunk.sp, chunk.dp, chunk.w, step,
                 )
-                # block before the reader recycles this chunk's buffer: on
-                # CPU jax the jitted fold may zero-copy ALIAS the staged
-                # numpy arrays, and dispatch is async — advancing the
-                # iterator would let the prefetch thread overwrite memory a
-                # pending computation still reads. Disk I/O still overlaps:
-                # the producer thread reads ahead while we wait on compute.
-                jax.block_until_ready(state["cnt"])
+                # the fold may still read (on CPU jax: alias) the staged
+                # arrays after dispatch returns: the reader refills them
+                # once its result is ready
+                chunk.release_after(state["cnt"])
         close_cur()
         flush_batch()
 
@@ -1150,21 +1169,16 @@ class GraphDEngine:
         # U_c ∥ U_s: the reader thread stages chunk t+1 while fold digests
         # chunk t
         if log is None:
+            rows = _source_rows(values, pg.degree, active)
             for chunk in reader.stream(schedule):
                 i, k = chunk.src_shard, chunk.dst_shard
                 with spans.span("stream.fold"):
                     A_r[k], cnt[k] = self._stream_fold(
-                        A_r[k], cnt[k], values[i], pg.degree[i], active[i],
+                        A_r[k], cnt[k], *rows(i),
                         chunk.sp, chunk.dp, chunk.w, step,
                     )
-                    # block before the reader recycles this chunk's buffer:
-                    # on CPU jax the jitted fold may zero-copy ALIAS the
-                    # staged numpy arrays, and dispatch is async — advancing
-                    # the iterator would let the prefetch thread overwrite
-                    # memory a pending computation still reads. Disk I/O
-                    # still overlaps: the producer thread reads ahead while
-                    # we wait on compute.
-                    jax.block_until_ready(cnt[k])
+                    # refill the staged arrays once the fold has read them
+                    chunk.release_after(cnt[k])
         else:
             # create the step's run store up front: even an all-skipped
             # superstep must publish an (empty) index or recovery of that
@@ -1497,6 +1511,7 @@ class GraphDEngine:
         n_active = n_msgs = 0
         agg = 0.0
         blocks = kib = 0
+        rows = _source_rows(values, pg.degree, active)
         ok = False
         try:
             for k in range(n):
@@ -1513,8 +1528,7 @@ class GraphDEngine:
                     cur_src = i
                     with spans.span("stream.fold"):
                         msg, dp, valid = self._stream_msgs(
-                            values[i], pg.degree[i], active[i],
-                            chunk.sp, chunk.dp, chunk.w, step,
+                            *rows(i), chunk.sp, chunk.dp, chunk.w, step,
                         )
                         # np.asarray both blocks on the async result and
                         # copies out of the reader's recycled staging buffers
@@ -1636,6 +1650,7 @@ class GraphDEngine:
             store, np.asarray(active), by_dest=True
         )
         residency = self._residency
+        totals = self._stream_reader.totals
         nonempty_total = store.nonempty_blocks()
         if comb is None:
             superstep = self._superstep_streamed_nocomb
@@ -1657,6 +1672,8 @@ class GraphDEngine:
                     )
                     residency.note_skipped(nonempty_total - scheduled)
                     hits0, miss0, evict0, _ = residency.counters()
+                    waits0 = totals.recycle_waits
+                    wait_s0 = totals.recycle_wait_seconds
                 values, active, n_active, n_msgs, agg, io_note = superstep(
                     values, active, s, plan
                 )
@@ -1674,6 +1691,8 @@ class GraphDEngine:
                 blocks_read=miss1 - miss0, cache_hits=hits1 - hits0,
                 cache_evictions=evict1 - evict0,
                 blocks_skipped=nonempty_total - scheduled,
+                recycle_waits=totals.recycle_waits - waits0,
+                recycle_wait_seconds=totals.recycle_wait_seconds - wait_s0,
                 phase_seconds=phases,
             )
             history.append(rec)

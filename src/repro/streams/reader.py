@@ -22,7 +22,7 @@ import queue
 import sys
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, fields
 
 import numpy as np
 
@@ -41,6 +41,16 @@ class StagedChunk:
     w: np.ndarray
     n_real_blocks: int
     _buf_id: int = -1  # pool slot, returned to the free list after consumption
+    _token: object = None  # see release_after
+
+    def release_after(self, token) -> None:
+        """Keep this chunk's buffers from being refilled until ``token``
+        is ready, not merely until the consumer advances. ``token`` is a
+        jax array that the device work reading the buffers returns (any
+        object with ``is_ready()`` and ``block_until_ready()``). The
+        consumer may then move on while that work is still queued: the
+        staging thread waits on the token instead."""
+        self._token = token
 
 
 @dataclass
@@ -55,9 +65,17 @@ class StreamStats:
     read_seconds: float = 0.0
     # consumer time spent blocked on the producer (span "stream.stage_wait")
     wait_seconds: float = 0.0
+    # refills that waited on an unfinished release_after token, and the
+    # producer's seconds spent so (outside "stream.stage")
+    recycle_waits: int = 0
+    recycle_wait_seconds: float = 0.0
 
     def throughput_edges_per_s(self) -> float:
         return self.edges_staged / self.read_seconds if self.read_seconds else 0.0
+
+    def add(self, other: "StreamStats") -> None:
+        for f in fields(self):
+            setattr(self, f.name, getattr(self, f.name) + getattr(other, f.name))
 
 
 _DONE = object()
@@ -165,6 +183,9 @@ class StreamReader:
         # count REAL reads, not staged blocks
         self.residency = residency
         self.stats = StreamStats()
+        # every finished pass's stats summed: a superstep's counters are
+        # differences of these
+        self.totals = StreamStats()
         self._worker: threading.Thread | None = None
         self._stop = threading.Event()
         # owner_views: read each source shard's blocks through a view that
@@ -194,8 +215,10 @@ class StreamReader:
     def stream(self, schedule):
         """Yield :class:`StagedChunk`s for ``schedule`` (list of
         ``(i, k, block_ids)``), prefetched ``depth`` chunks ahead by a
-        background thread. The yielded buffers are only valid until the next
-        iteration (the engine copies them to device on consumption)."""
+        background thread. A yielded chunk's buffers are refilled once the
+        consumer advances past it, or, if the consumer gave the chunk a
+        token (:meth:`StagedChunk.release_after`), once that token is
+        ready: device work may read them until then."""
         # guard against a producer left over from an aborted pass: stop it
         # before starting a new one, and never share buffers with it
         prev = self._worker
@@ -221,9 +244,10 @@ class StreamReader:
             for _ in range(self.depth + 1)
         ]
         full: queue.Queue = queue.Queue(maxsize=self.depth)
+        # (buffer id, release token or None) of every buffer free to refill
         free: queue.Queue = queue.Queue()
         for bid in range(len(pool)):
-            free.put(bid)
+            free.put((bid, None))
         stop = self._stop = threading.Event()
 
         def _put(item) -> bool:
@@ -235,12 +259,23 @@ class StreamReader:
                     pass
             return False
 
+        def _released(token) -> bool:
+            """Wait until the device is done with a buffer; False on stop.
+            A token is device work already dispatched, so the wait ends."""
+            if token is None or token.is_ready():
+                return True
+            t0 = time.perf_counter()
+            token.block_until_ready()
+            stats.recycle_waits += 1
+            stats.recycle_wait_seconds += time.perf_counter() - t0
+            return not stop.is_set()
+
         def _produce():
             try:
                 for i, k, ids in schedule:
                     for off in range(0, len(ids), CB):
-                        bid = free.get()
-                        if stop.is_set():
+                        bid, token = free.get()
+                        if stop.is_set() or not _released(token):
                             return
                         sp, dp, w = pool[bid]
                         with spans.span("stream.stage") as staged:
@@ -279,12 +314,12 @@ class StreamReader:
                 with spans.span("stream.stage_wait") as waited:
                     item = full.get()
                 stats.wait_seconds += waited.seconds
-                # the consumer has moved past the previous chunk — its buffer
-                # can be refilled. The consumer MUST have finished reading it
-                # (jnp may alias, not copy, these arrays on CPU; the engine
-                # blocks on the fold's result before advancing)
+                # the consumer has moved past the previous chunk: its buffer
+                # can be refilled once the chunk's token, if any, is ready.
+                # Without a token the consumer MUST have finished reading it
+                # (jax may alias, not copy, these arrays on CPU)
                 if held is not None:
-                    free.put(held._buf_id)
+                    free.put((held._buf_id, held._token))
                     held = None
                 if item is _DONE:
                     break
@@ -295,8 +330,9 @@ class StreamReader:
         finally:
             stop.set()
             # unblock a producer waiting on a free buffer, then drain
-            free.put(0)
+            free.put((0, None))
             worker.join(timeout=5.0)
+            self.totals.add(stats)
             # the staging thread's seconds count in the consumer's phases
             spans.add("stream.stage", stats.read_seconds)
             if worker.is_alive() and sys.exc_info()[1] is None:

@@ -4,7 +4,11 @@ guarantee, skip()-driven I/O avoidance, and manifest-aware recovery."""
 
 import collections
 import os
+import threading
+import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -12,12 +16,14 @@ from repro.core import (
     ChannelConfig, EngineConfig, GraphDEngine, HashMin, PageRank, SSSP,
     StreamConfig,
 )
-from repro.core.checkpoint import Checkpointer
+from repro.core.checkpoint import Checkpointer, RunFileMessageLog
 from repro.graph import (
     chain_graph, partition_graph, partition_graph_streamed, rmat_graph,
     spill_partition,
 )
-from repro.streams import EdgeStreamStore, StreamReader, plan_stream_schedule
+from repro.streams import (
+    EdgeStreamStore, StagedChunk, StreamReader, plan_stream_schedule,
+)
 
 
 @pytest.fixture
@@ -137,6 +143,126 @@ class TestStreamReader:
         assert r.staging_bytes() == 3 * (4 * B * 12)  # (depth+1) buffers
 
 
+class _Token:
+    """Stand-in for a fold's result: ready once ``done`` is set."""
+
+    def __init__(self, ready: bool = False):
+        self.done = threading.Event()
+        if ready:
+            self.done.set()
+
+    def is_ready(self) -> bool:
+        return self.done.is_set()
+
+    def block_until_ready(self) -> None:
+        assert self.done.wait(timeout=10.0)
+
+
+def _full_schedule(store, P):
+    schedule, _, _ = plan_stream_schedule(store, np.ones((4, P), bool))
+    return schedule
+
+
+class TestReleaseTokens:
+    """A chunk given a release token keeps its staging buffers until the
+    token is ready; a chunk without one is released when the consumer
+    advances."""
+
+    def test_unfinished_token_holds_the_buffer(self, spilled):
+        _, _, pg, _, store = spilled
+        reader = StreamReader(store, chunk_blocks=1, depth=1)  # 2 buffers
+        it = reader.stream(_full_schedule(store, pg.P))
+        c0 = next(it)
+        snap = (c0.sp.copy(), c0.dp.copy(), c0.w.copy())
+        t0 = _Token()
+        c0.release_after(t0)
+        next(it)  # advance: c0's buffer goes back to the pool under t0
+        time.sleep(0.3)  # the staging thread wants that buffer for chunk 2
+        assert np.array_equal(c0.sp, snap[0])
+        assert np.array_equal(c0.dp, snap[1])
+        assert np.array_equal(c0.w, snap[2])
+        t0.done.set()
+        c2 = next(it)
+        assert np.shares_memory(c2.sp, c0.sp)  # refilled only now
+        assert not np.array_equal(c2.dp, snap[1])
+        for _ in it:
+            pass
+        assert reader.stats.recycle_waits == 1
+        assert reader.stats.recycle_wait_seconds >= 0.25
+        assert reader.totals.recycle_waits == 1
+
+    def test_chunk_without_token_released_on_advance(self, spilled):
+        _, _, pg, _, store = spilled
+        schedule = _full_schedule(store, pg.P)
+        reader = StreamReader(store, chunk_blocks=1, depth=1)
+        chunks, copies = [], []
+        for chunk in reader.stream(schedule):
+            chunks.append(chunk)
+            copies.append((chunk.sp.copy(), chunk.dp.copy()))
+        # two buffers: chunk n+2 is staged into chunk n's buffer
+        assert np.shares_memory(chunks[0].sp, chunks[2].sp)
+        assert reader.stats.recycle_waits == 0
+        # ... and every chunk held its own edges when it was yielded
+        B = store.geom.edge_block
+        want = []
+        for i, k, ids in schedule:
+            for o in range(len(ids)):
+                sp, dp = np.empty((1, B), np.int32), np.empty((1, B), np.int32)
+                store.read_blocks(i, k, ids[o:o + 1], sp, dp,
+                                  np.empty((1, B), np.float32))
+                want.append((sp.reshape(-1), dp.reshape(-1)))
+        assert len(copies) == len(want)
+        for (sp, dp), (sp_w, dp_w) in zip(copies, want):
+            assert np.array_equal(sp, sp_w) and np.array_equal(dp, dp_w)
+
+    def test_ready_tokens_cost_no_wait(self, spilled):
+        _, _, pg, _, store = spilled
+        reader = StreamReader(store, chunk_blocks=1, depth=1)
+        n = 0
+        for chunk in reader.stream(_full_schedule(store, pg.P)):
+            chunk.release_after(_Token(ready=True))
+            n += 1
+        assert reader.stats.chunks == n
+        assert reader.stats.recycle_waits == 0
+
+    def test_abort_skips_the_held_chunks_token(self, spilled):
+        """Aborted while the staging thread waits for a free buffer: the
+        unblock hands it no token, whatever the held chunk carries."""
+        _, _, pg, _, store = spilled
+        reader = StreamReader(store, chunk_blocks=1, depth=1)
+        it = reader.stream(_full_schedule(store, pg.P))
+        next(it).release_after(_Token())  # never ready, never advanced past
+        time.sleep(0.1)  # chunk 1 staged; the staging thread needs a buffer
+        t0 = time.perf_counter()
+        it.close()  # no RuntimeError from the 5 s join guard
+        assert time.perf_counter() - t0 < 2.0
+        assert not reader._worker.is_alive()
+        # the reader runs a fresh pass afterwards
+        assert sum(1 for _ in reader.stream(_full_schedule(store, pg.P))) > 0
+
+    def test_abort_during_a_token_wait_refills_nothing(self, spilled):
+        """Aborted while the staging thread waits on a token: it stops once
+        the device work is done, and stages nothing more."""
+        _, _, pg, _, store = spilled
+        reader = StreamReader(store, chunk_blocks=1, depth=1)
+        it = reader.stream(_full_schedule(store, pg.P))
+        c0 = next(it)
+        snap = c0.sp.copy()
+        token = _Token()
+        c0.release_after(token)
+        next(it)
+        time.sleep(0.1)  # the staging thread now waits on the token
+        timer = threading.Timer(0.3, token.done.set)
+        timer.start()
+        try:
+            it.close()
+        finally:
+            timer.join()
+        assert not reader._worker.is_alive()
+        assert reader.stats.chunks == 2
+        assert np.array_equal(c0.sp, snap)  # not refilled after the stop
+
+
 # ---------------------------------------------------------------------------
 # cross-mode equivalence: streamed must agree with every in-memory mode
 # ---------------------------------------------------------------------------
@@ -195,6 +321,108 @@ class TestCrossModeEquivalence:
         ref = outs["recoded"]
         for mode, got in outs.items():
             assert got == ref, mode  # integer labels: bit-for-bit
+
+
+# ---------------------------------------------------------------------------
+# early recycle: one-block chunks and two staging buffers, so every buffer is
+# refilled as soon as the reader may. A fold that still read a refilled
+# buffer would change the values.
+# ---------------------------------------------------------------------------
+
+RECYCLE_PROGRAMS = {
+    "pagerank": lambda g, rmap: PageRank(supersteps=5),
+    "sssp": lambda g, rmap: SSSP(
+        int(rmap.to_new(np.array([int(g.vertex_ids[0])]))[0])),
+    "hashmin": lambda g, rmap: HashMin(),
+}
+# the combiner loops that hand the reader release tokens
+RECYCLE_PATHS = ["unpipelined", "full-duplex", "logged"]
+
+
+@pytest.fixture(scope="module")
+def recycle_graph(tmp_path_factory):
+    g = rmat_graph(scale=7, edge_factor=8, seed=3, weights="uniform")
+    pg, rmap = partition_graph(g, n_shards=4, edge_block=16)
+    d = tmp_path_factory.mktemp("recycle")
+    pgs, _, store = partition_graph_streamed(
+        g, 4, str(d / "spill"), edge_block=16, recode=rmap
+    )
+    return g, rmap, pg, pgs, store, d
+
+
+def _late_reading(fold):
+    """``fold`` that reads its staged edges only after about 2 ms of busy
+    work, so the device is still on a chunk when the reader wants its
+    buffer back. The edges get ``+ 0``: the same values."""
+
+    @jax.jit
+    def late(A, cnt, values, degree, active, sp, dp, w, step):
+        x = jnp.arange(1 << 14, dtype=jnp.float32) * step.astype(jnp.float32)
+        acc = jax.lax.fori_loop(
+            0, 8, lambda r, a: a + jnp.sum(jnp.sin(x + r)), jnp.float32(0)
+        )
+        zero = (acc * 0).astype(jnp.int32)
+        return fold(A, cnt, values, degree, active, sp + zero, dp + zero,
+                    w + zero.astype(w.dtype), step)
+
+    return late
+
+
+@pytest.mark.parametrize("path", RECYCLE_PATHS)
+@pytest.mark.parametrize("algo", sorted(RECYCLE_PROGRAMS))
+def test_no_early_recycle(recycle_graph, algo, path, monkeypatch):
+    g, rmap, pg, pgs, store, d = recycle_graph
+    make = RECYCLE_PROGRAMS[algo]
+
+    def streamed(tag):
+        log = (RunFileMessageLog(str(d / f"log-{algo}-{tag}"))
+               if path == "logged" else None)
+        eng = GraphDEngine(
+            pgs, make(g, rmap),
+            config=EngineConfig(
+                mode="streamed",
+                stream=StreamConfig(chunk_blocks=1, depth=1),
+                channel=ChannelConfig(pipeline=path == "full-duplex"),
+            ),
+            stream_store=store, message_log=log,
+        )
+        eng._stream_fold = _late_reading(eng._stream_fold)
+        (vals, _), hist = eng.run()
+        return np.asarray(vals), eng.gather_values(vals), hist
+
+    # the reference protocol: wait for each fold, then release on advance
+    with monkeypatch.context() as m:
+        m.setattr(StagedChunk, "release_after",
+                  lambda self, token: jax.block_until_ready(token))
+        v_sync, _, _ = streamed("sync")
+    released = []
+    release_after = StagedChunk.release_after
+
+    def spy(self, token):
+        released.append(token)
+        release_after(self, token)
+
+    monkeypatch.setattr(StagedChunk, "release_after", spy)
+    v, got, hist = streamed("async")
+    # the same ops in the same order: bit-identical to the waiting loop
+    assert np.array_equal(v, v_sync)
+    eng = GraphDEngine(pg, make(g, rmap), config=EngineConfig(mode="recoded"))
+    (vals, _), _ = eng.run()
+    ref = eng.gather_values(vals)
+    if algo == "pagerank":  # float sums, reassociated by the grouping
+        assert max(abs(got[k] - ref[k]) for k in ref) < 1e-6
+    else:
+        assert got == ref
+    chunks = sum(r.blocks_read + r.cache_hits for r in hist)
+    if path == "unpipelined":
+        assert len(released) == chunks  # every chunk's fold is a token
+    else:  # single-chunk groups are copied into a batched fold instead
+        assert 0 < len(released) <= chunks
+    for r in hist:
+        assert 0 <= r.recycle_waits <= r.blocks_read + r.cache_hits
+        assert r.recycle_wait_seconds >= 0.0
+    # the late reads made the reader wait for the device at least once
+    assert sum(r.recycle_waits for r in hist) > 0
 
 
 # ---------------------------------------------------------------------------
