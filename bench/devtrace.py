@@ -16,6 +16,14 @@ from the first superstep's start to the last superstep's end.
   device runs) is named after the innermost host event that covers its
   midpoint on the thread that holds the harness's spans: one of those
   spans, or an event JAX records inside them (a dispatch, a transfer).
+
+Under ``launch="processes"`` each worker traces its own chip (the probe,
+``bench/probe/sitecustomize.py``), and :func:`summarize_workers` reduces
+those traces over one window given on the wall clock: each chip's busy
+time over that window, the operations' seconds summed over the chips, and
+each chip's idle gaps named after the innermost host event on its
+worker's main thread (the thread that holds the probe's
+:data:`BARRIER_SPAN`), summed over the chips by name.
 """
 
 from __future__ import annotations
@@ -27,6 +35,8 @@ from dataclasses import dataclass, field
 
 RUN_SPAN = "GraphDJob.run"
 STEP_SPAN = "on_step"
+#: the probe's span around a worker's barrier wait (its main thread's)
+BARRIER_SPAN = "bench.barrier_wait"
 OPS_LINE = "XLA Ops"
 #: host events that mark scheduling, not work, and name no gap
 _NOT_WORK = ("ThreadpoolListener::",)
@@ -117,7 +127,12 @@ def read(path: str):
     :data:`RUN_SPAN`."""
     from jax.profiler import ProfileData
 
-    pd = ProfileData.from_file(path)
+    return _read(ProfileData.from_file(path), RUN_SPAN)
+
+
+def _read(pd, mark: str):
+    """:func:`read` of a loaded ``ProfileData``, keeping the host threads
+    that hold a ``mark`` span."""
     devices, host_lines, cpu_ops = {}, [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
@@ -143,7 +158,7 @@ def read(path: str):
                 else:
                     events.append(span)
             # only the harness's thread names gaps
-            if any(n == RUN_SPAN for n, _, _ in events):
+            if any(n == mark for n, _, _ in events):
                 host_lines.append(events)
     if not devices and cpu_ops:
         devices["/host:CPU"] = cpu_ops
@@ -160,6 +175,13 @@ def summarize(devices: dict, host_lines: list, top: int = 10) -> TraceSummary:
     lo = min(a for n, a, _ in main if n == RUN_SPAN)
     ends = [b for n, _, b in main if n == STEP_SPAN]
     hi = max(ends) if ends else max(b for n, _, b in main if n == RUN_SPAN)
+    return reduce_window(devices, main, lo, hi, top)
+
+
+def reduce_window(devices: dict, main: list, lo: float, hi: float,
+                  top: int = 10) -> TraceSummary:
+    """Reduce one trace's ``devices`` over ``[lo, hi]`` (its own clock),
+    naming idle gaps after the events of the host thread ``main``."""
     if not devices:
         raise ValueError("the trace holds no device operation")
     busy, per_op, every = 0.0, defaultdict(float), []
@@ -177,13 +199,60 @@ def summarize(devices: dict, host_lines: list, top: int = 10) -> TraceSummary:
     for (a, b), name in zip(idle, innermost(main, [(a + b) / 2
                                                    for a, b in idle])):
         per_gap[name] += (b - a) * 1e-9
-    rank = lambda d: [[k, v] for k, v in sorted(
-        d.items(), key=lambda kv: -kv[1])[:top]]
     return TraceSummary(window_s=(hi - lo) * 1e-9,
                         busy_s=busy * 1e-9 / len(devices),
-                        n_devices=len(devices), device_ops=rank(per_op),
-                        idle_gaps=rank(per_gap))
+                        n_devices=len(devices),
+                        device_ops=_rank(per_op, top),
+                        idle_gaps=_rank(per_gap, top))
 
 
 def summarize_dir(log_dir: str) -> TraceSummary:
     return summarize(*read(find_xplane(log_dir)))
+
+
+def _rank(d: dict, top: int) -> list:
+    return [[k, v] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:top]]
+
+
+def profile_start_ns(pd) -> int:
+    """The wall-clock nanoseconds from which the trace's events count."""
+    for plane in pd.planes:
+        for k, v in plane.stats:
+            if k == "profile_start_time":
+                return int(v)
+    raise ValueError("the trace records no profile_start_time")
+
+
+def combine(summaries: list, top: int = 10) -> TraceSummary:
+    """One summary of the chips of ``summaries`` (each over the same
+    window): busy time averaged over all their devices, operations' and
+    idle gaps' seconds summed over them by name."""
+    n = sum(s.n_devices for s in summaries)
+    ops, idle = defaultdict(float), defaultdict(float)
+    for s in summaries:
+        for name, sec in s.device_ops:
+            ops[name] += sec
+        for name, sec in s.idle_gaps:
+            idle[name] += sec
+    return TraceSummary(
+        window_s=sum(s.window_s for s in summaries) / len(summaries),
+        busy_s=sum(s.busy_s * s.n_devices for s in summaries) / n,
+        n_devices=n, device_ops=_rank(ops, top), idle_gaps=_rank(idle, top))
+
+
+def summarize_workers(log_dirs: list, wall_lo: float, wall_hi: float,
+                      top: int = 10) -> TraceSummary:
+    """Reduce one trace per worker (``log_dirs``) over the window from
+    ``wall_lo`` to ``wall_hi`` (``time.time()`` seconds) to one summary."""
+    from jax.profiler import ProfileData
+
+    out = []
+    for d in log_dirs:
+        pd = ProfileData.from_file(find_xplane(d))
+        t0 = profile_start_ns(pd)
+        devices, lines = _read(pd, BARRIER_SPAN)
+        # each worker's whole list of top entries, so the sums stay whole
+        out.append(reduce_window(devices, lines[0] if lines else [],
+                                 wall_lo * 1e9 - t0, wall_hi * 1e9 - t0,
+                                 top=1 << 30))
+    return combine(out, top)

@@ -24,15 +24,29 @@ readers of the cell's metrics by name (see ``bench/__init__.py``).
 Set-up is everything from process start to the window's start less the
 seconds of step 1; the window runs from the timed call's start to its
 last superstep's end.
+
+A configuration whose ``launch.mode`` is ``"processes"`` runs one worker
+process per chip (``repro.launch.procs``), and this process never opens a
+chip. ``GraphDJob.run`` spawns its workers anew at every call, so such a
+cell differs in three places: the warm-up runs two supersteps, and the
+second one's seconds give ``K``; the timed call runs ``1 + K``
+supersteps, and its first, which carries the workers' spawn, their JAX
+start and their program loads, belongs to set-up, so that the window runs
+from that superstep's end to the last one's; and what only the process
+that holds a chip can read (its memory peak, its trace, its compiles and
+its barrier waits) comes from the probe, ``bench/probe/sitecustomize.py``,
+which the harness loads into each worker of the timed call.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import importlib.util
 import json
 import os
 import shutil
+import statistics
 import sys
 import tempfile
 import threading
@@ -145,6 +159,12 @@ class RunRecord:
     channel_stats: dict | None = None  # GraphDEngine.channel_stats, if any
     trace: devtrace.TraceSummary | None = None
     device_kind: str = ""
+    # launch="processes" only: the timed call's start to its first
+    # superstep's end, less the window's median superstep
+    spawn_s: float | None = None
+    # launch="processes" only: each worker's seconds in each barrier wait
+    # of the window's supersteps
+    barrier_waits: list | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -247,17 +267,18 @@ def make_program(traffic: dict, vertex_ids, n_shards: int):
     return getattr(core, spec["class"])(**args)
 
 
-def budget_for(program, metas, n, rule_out, want):
-    """A RAM budget per shard under which the planner picks a plan that
-    ``want`` accepts for each of ``metas``: one byte under candidate
-    ``rule_out``'s floor (the candidates of ``PlanInfeasible.breakdown``)
-    for the first, walking down in 5 % steps, so the plan keeps the
-    largest knobs that fit."""
+def budget_for(program, metas, n, rule_out, want, launch="threads"):
+    """A RAM budget per shard under which the planner, planning for
+    ``launch``, picks a plan that ``want`` accepts for each of ``metas``:
+    one byte under candidate ``rule_out``'s floor (the candidates of
+    ``PlanInfeasible.breakdown``) for the first, walking down in 5 % steps,
+    so the plan keeps the largest knobs that fit."""
     from repro.core import MemoryBudget, plan
     from repro.core.plan import PlanInfeasible
 
     try:
-        plan(program, metas[0], MemoryBudget(ram_per_shard=1, n_shards=n))
+        plan(program, metas[0], MemoryBudget(ram_per_shard=1, n_shards=n),
+             launch=launch)
     except PlanInfeasible as e:
         floors = {c["name"]: c["ram_total"]
                   for c in e.breakdown["candidates"]}
@@ -265,7 +286,7 @@ def budget_for(program, metas, n, rule_out, want):
     while ram > 0:
         budget = MemoryBudget(ram_per_shard=ram, n_shards=n)
         try:
-            if all(want(plan(program, m, budget))
+            if all(want(plan(program, m, budget, launch=launch))
                    for m in metas):
                 return budget
         except PlanInfeasible:
@@ -292,6 +313,13 @@ def realized_meta(graph, n: int, vertex_pad: int = 8):
                      for_n_shards=n)
 
 
+def launch_of(config: dict) -> tuple[str, dict]:
+    """The configuration's ``GraphDJob`` launch and its options
+    (``launch``: ``{"mode", "opts"}``; threads when it has none)."""
+    spec = config.get("launch") or {}
+    return spec.get("mode", "threads"), dict(spec.get("opts") or {})
+
+
 def make_budget(config: dict, program, graph):
     from repro.core import MemoryBudget
 
@@ -307,7 +335,8 @@ def make_budget(config: dict, program, graph):
     return budget_for(program, [GraphMeta.of(graph),
                                 realized_meta(graph, n)], n,
                       rule["below_floor_of"],
-                      lambda p: plan_matches(p, want))
+                      lambda p: plan_matches(p, want),
+                      launch=launch_of(config)[0])
 
 
 def control_plan(program, graph, budget, config: dict):
@@ -316,7 +345,7 @@ def control_plan(program, graph, budget, config: dict):
     check must fail."""
     from repro.core import plan
 
-    p = plan(program, graph, budget)
+    p = plan(program, graph, budget, launch=launch_of(config)[0])
     cfg = dataclasses.replace(p.config, channel=dataclasses.replace(
         p.config.channel, **config["control"]["channel"]))
     cfg.finalize()
@@ -336,6 +365,66 @@ def values_array(values: dict, labels: np.ndarray) -> tuple[np.ndarray, int]:
     out = np.full(n, np.nan)
     out[order[j[ok]]] = vals[ok]
     return out, n - int(np.unique(j[ok]).size)
+
+
+# ---------------------------------------------------------------------------
+# the workers of launch="processes"
+# ---------------------------------------------------------------------------
+
+#: put first on the ``PYTHONPATH`` of the timed call's workers, so that each
+#: imports the probe's ``sitecustomize``
+PROBE = os.path.join(BENCH, "probe")
+_PROBE_ENV = ("PYTHONPATH", "GRAPHD_PROBE_DIR", "GRAPHD_PROBE_TRACE")
+
+
+class WorkerProbe:
+    """The probe in every worker that ``GraphDJob.run`` spawns inside the
+    ``with`` block (the workers inherit this process's environment), and
+    what it wrote: :meth:`records`, one dict a worker, after the call."""
+
+    def __init__(self, out_dir: str, trace: bool):
+        self.out_dir = out_dir
+        self.trace = trace
+
+    def __enter__(self) -> "WorkerProbe":
+        self._saved = {k: os.environ.get(k) for k in _PROBE_ENV}
+        path = os.environ.get("PYTHONPATH")
+        os.environ["PYTHONPATH"] = os.pathsep.join([PROBE] + ([path] if path
+                                                              else []))
+        os.environ["GRAPHD_PROBE_DIR"] = self.out_dir
+        os.environ["GRAPHD_PROBE_TRACE"] = "1" if self.trace else "0"
+        return self
+
+    def __exit__(self, *exc) -> None:
+        for k, v in self._saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+    def records(self, n: int) -> list[dict]:
+        out = []
+        for w in range(n):
+            path = os.path.join(self.out_dir, f"shard-{w}.json")
+            if not os.path.isfile(path):
+                raise RuntimeError(f"worker {w} left no probe record "
+                                   f"({path})")
+            with open(path) as f:
+                out.append(json.load(f))
+        return out
+
+
+def worker_device(cell: Cell, devices: list) -> dict:
+    """The chips of a processes cell as its workers report them
+    (``JobResult.devices``); fail unless each is a TPU (no worker falls
+    back to the CPU) and there are as many as the cell asks for."""
+    platforms = sorted({d["platform"] for d in devices})
+    if platforms != ["tpu"]:
+        raise NoChip(f"no TPU: the workers found {platforms} devices")
+    if len(devices) < cell.chips:
+        raise NoChip(f"{cell.name} needs {cell.chips} TPU chips; "
+                     f"{len(devices)} workers reported one")
+    return dict(platform="tpu", kind=devices[0]["kind"], count=len(devices))
 
 
 # ---------------------------------------------------------------------------
@@ -359,7 +448,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     """Run ``cell`` once; returns ``(result, checks)``: the result line's
     object (without ``checks``) and the numbers compared with their
     limits. ``device`` stands in for the chip's (the tests run on the
-    CPU); without it the run opens its TPU once the graph is made."""
+    CPU); without it the run opens its TPU once the graph is made, or,
+    for a processes cell, takes its workers' chips."""
     import jax
 
     from repro.core import GraphDJob
@@ -367,6 +457,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     t_start = process_start() if t_start is None else t_start
     cfg, traffic, ref = cell.config, cell.traffic, cell.reference
+    launch, launch_opts = launch_of(cfg)
+    procs = launch == "processes"
     cache = cache or os.path.join(BENCH, ".cache")
     # the benchmark's data: made in a child process, which frees the chip
     # and its host memory before this process opens the chip
@@ -380,7 +472,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     data_s = time.perf_counter() - t0
     log(f"{cell.name}: graph {made['cache']} {made['seconds']:.3f} s, "
         f"labelled from the seed, {data_s:.3f} s in all (not set-up)")
-    if device is None:
+    if device is None and not procs:
         device = open_device(cell)
     n = labels.size
     program = make_program(traffic, graph.vertex_ids, int(cfg["n_shards"]))
@@ -389,23 +481,28 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     os.makedirs(work_root, exist_ok=True)
     workdir = tempfile.mkdtemp(prefix=f"{cell.name}-", dir=work_root)
     clock = CompileClock()
-    trace_dir = (tempfile.mkdtemp(prefix=f"trace-{cell.name}-",
-                                  dir=work_root) if trace else None)
+    # a processes cell's probe writes its records (and traces) here
+    trace_dir = (tempfile.mkdtemp(
+        prefix=f"{'probe' if procs else 'trace'}-{cell.name}-",
+        dir=work_root) if trace or procs else None)
     try:
         t0 = time.perf_counter()
         ctl = cfg["control"] if control else {}
         if "channel" in ctl:
             job = GraphDJob(program, graph, workdir=workdir,
-                            plan=control_plan(program, graph, budget, cfg))
+                            plan=control_plan(program, graph, budget, cfg),
+                            launch=launch, launch_opts=launch_opts)
         else:
-            job = GraphDJob(program, graph, budget=budget, workdir=workdir)
+            job = GraphDJob(program, graph, budget=budget, workdir=workdir,
+                            launch=launch, launch_opts=launch_opts)
         partition_s = time.perf_counter() - t0
         p = job.plan
         log(f"{cell.name}: plan mode={p.mode} pipeline={p.pipeline} "
             f"full_duplex={p.config.channel.full_duplex} "
             f"compress_payload={p.config.channel.compress_payload} "
-            f"n_shards={p.n_shards} ram_per_shard={budget.ram_per_shard}; "
-            f"partition {partition_s:.3f} s; edge store on disk "
+            f"n_shards={p.n_shards} ram_per_shard={budget.ram_per_shard} "
+            f"launch={launch}; partition {partition_s:.3f} s; edge store "
+            f"on disk "
             f"{job.store.disk_bytes() if job.store is not None else 0} B")
         if not ctl and not plan_matches(p, cfg["plan"]):
             raise SpecError(f"{cell.name}: the planner chose mode={p.mode} "
@@ -414,54 +511,115 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                             f"asks for {cfg['plan']}")
         # warm-up: every program compiles or loads from the cache here
         compiled0 = clock.seconds
-        warm = job.run(max_supersteps=1)
+        warm_steps = 2 if procs else 1
+        warm = job.run(max_supersteps=warm_steps)
         first_step_s = warm.history[0].seconds
-        # the warm-up superstep less its compiles; the floor keeps K from
-        # growing past a few times the window where they overlap
-        step_est = max(first_step_s - (clock.seconds - compiled0),
-                       0.25 * first_step_s)
+        if procs:
+            # the first superstep spawned the workers: the second is a
+            # superstep alone
+            step_est = warm.history[1].seconds
+            if device is None:
+                device = worker_device(cell, warm.devices)
+        else:
+            # the warm-up superstep less its compiles; the floor keeps K
+            # from growing past a few times the window where they overlap
+            step_est = max(first_step_s - (clock.seconds - compiled0),
+                           0.25 * first_step_s)
         k = max(2, round(seconds / step_est))
         log(f"{cell.name}: set-up built {clock.compiles} program(s), "
             f"{clock.misses} compiled anew "
             f"({'a cold run' if clock.misses else 'all from the cache'}), "
             f"{clock.seconds:.3f} s of tracing, lowering and compiling; "
-            f"warm-up superstep {first_step_s:.3f} s, superstep estimate "
-            f"{step_est:.3f} s -> K={k}")
+            f"warm-up supersteps "
+            f"{[round(r.seconds, 3) for r in warm.history]} s, superstep "
+            f"estimate {step_est:.3f} s -> K={k}")
 
-        stamps = []  # (perf_counter, record) per superstep
+        stamps = []  # (perf_counter, wall clock, record) per superstep
 
         def on_step(rec, _state):
             with jax.profiler.TraceAnnotation(devtrace.STEP_SPAN):
-                stamps.append((time.perf_counter(), rec))
+                stamps.append((time.perf_counter(), time.time(), rec))
 
-        if trace:
+        probe = WorkerProbe(trace_dir, trace) if procs else None
+        if trace and not procs:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
         compiles0 = clock.compiles
+        # a processes call runs one more superstep: its first, the spawn
+        target = warm_steps + int(procs) + k
         t_call, wall_call = time.perf_counter(), time.time()
         try:
-            with jax.profiler.TraceAnnotation(devtrace.RUN_SPAN):
-                res = job.run(max_supersteps=1 + k, on_step=on_step)
+            with (probe or contextlib.nullcontext()), \
+                    jax.profiler.TraceAnnotation(devtrace.RUN_SPAN):
+                res = job.run(max_supersteps=target, on_step=on_step)
         finally:
-            if trace:
+            if trace and not procs:
                 jax.profiler.stop_trace()
-        window_s = stamps[-1][0] - t_call
-        setup_s = wall_call - t_start - data_s
+        if procs:
+            (t0, wall0, _), timed = stamps[0], stamps[1:]
+        else:
+            t0, wall0, timed = t_call, wall_call, stamps
+        window = [r for _, _, r in timed]
+        window_s = stamps[-1][0] - t0
+        setup_s = wall0 - t_start - data_s
         if clock.compiles > compiles0:
             log(f"{cell.name}: WARNING {clock.compiles - compiles0} "
                 "compile(s) inside the window")
-        stats = jax.devices()[0].memory_stats() or {}
-        device = dict(device, memory_peak_bytes=stats.get(
-            "peak_bytes_in_use"))
-        stats = getattr(job.engine, "channel_stats", None)
-        channel = dataclasses.asdict(stats) if stats else None
+        spawn_s = barrier_waits = workers = None
+        if procs:
+            ends = [t for t, _, _ in stamps]
+            spawn_s = (ends[0] - t_call) - statistics.median(
+                b - a for a, b in zip(ends, ends[1:]))
+            workers = probe.records(p.n_shards)
+            peaks = [w["peak_bytes_in_use"] for w in workers]
+            device = dict(device, memory_peak_bytes=max(
+                (b for b in peaks if b is not None), default=None),
+                memory_peak_bytes_per_chip=peaks)
+            in_window = {r.step for r in window}
+            for w in workers:
+                timed_steps = {step for step, _, _ in w["barrier"]}
+                if not in_window <= timed_steps:
+                    raise RuntimeError(
+                        f"worker {w['shard']} timed no barrier wait in "
+                        f"supersteps {sorted(in_window - timed_steps)}: the "
+                        "probe found no FileCoordinator.wait_commit to time")
+            barrier_waits = [sec for w in workers
+                             for step, _, sec in w["barrier"]
+                             if step in in_window]
+            built = sum(len(w["compiles"]) for w in workers)
+            late = sum(t > wall0 for w in workers for t, _ in w["compiles"])
+            misses = sum(len(w["cache_misses"]) for w in workers)
+            log(f"{cell.name}: the timed call's workers built {built} "
+                f"program(s), {misses} compiled anew; spawn and first "
+                f"superstep {ends[0] - t_call:.3f} s")
+            if trace:
+                log(f"{cell.name}: the workers wrote their traces in "
+                    f"{[w['trace_stop_s'] for w in workers]} s")
+            if late:
+                log(f"{cell.name}: WARNING {late} compile(s) inside the "
+                    "window, in the workers")
+            channel = None
+        else:
+            stats = jax.devices()[0].memory_stats() or {}
+            device = dict(device, memory_peak_bytes=stats.get(
+                "peak_bytes_in_use"))
+            stats = getattr(job.engine, "channel_stats", None)
+            channel = dataclasses.asdict(stats) if stats else None
         steps = len(warm.history) + len(res.history)
         got, missing = values_array(res.values, labels)
         del res
         job.close(delete=True)
         del job
-        summary = devtrace.summarize_dir(trace_dir) if trace else None
+        summary = None
+        if trace and procs:
+            t0 = time.perf_counter()
+            summary = devtrace.summarize_workers(
+                [w["trace_dir"] for w in workers], wall0, stamps[-1][1])
+            log(f"{cell.name}: read the workers' traces in "
+                f"{time.perf_counter() - t0:.3f} s")
+        elif trace:
+            summary = devtrace.summarize_dir(trace_dir)
     finally:
         for d in (workdir, trace_dir):
             if d:
@@ -477,20 +635,20 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     limits = traffic["limits"]
     checks = {k: dict(value=numbers[k], limit=limits[k]) for k in limits}
     checks["missing_vertices"] = dict(value=missing, limit=0)
-    checks["missing_supersteps"] = dict(value=1 + k - steps, limit=0)
+    checks["missing_supersteps"] = dict(value=target - steps, limit=0)
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     log(f"{cell.name}: reference {steps} supersteps in "
         f"{time.perf_counter() - t0:.3f} s")
 
     per_step = ref.active_edges(src, dst, n, traffic, steps)
-    window = [r for _, r in stamps]
     run = RunRecord(
         cell=cell, n_vertices=n, setup_s=setup_s,
         partition_s=partition_s, first_step_s=first_step_s,
         window_s=window_s, window=window,
         window_edges=int(sum(per_step[r.step] for r in window)),
         plan_mode=p.mode, channel_stats=channel, trace=summary,
-        device_kind=device["kind"])
+        device_kind=device["kind"], spawn_s=spawn_s,
+        barrier_waits=barrier_waits)
     metrics = {}
     for entry, reader in (cell.per_layer if trace else cell.end_to_end):
         v = reader.read(run)

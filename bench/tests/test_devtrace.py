@@ -86,3 +86,68 @@ def test_recorded_cpu_trace():
     idle = sum(b - a for a, b in devtrace.gaps(devtrace.union(ops), lo, hi))
     assert idle * 1e-9 + s.busy_s == pytest.approx(s.window_s, rel=1e-9)
     assert sum(v for _, v in s.idle_gaps) <= idle * 1e-9 * (1 + 1e-9)
+
+
+def test_combine_sums_chips():
+    a = devtrace.TraceSummary(window_s=10.0, busy_s=2.0, n_devices=1,
+                              device_ops=[["fold", 1.5], ["copy", 0.5]],
+                              idle_gaps=[["bench.barrier_wait", 8.0]])
+    b = devtrace.TraceSummary(window_s=10.0, busy_s=4.0, n_devices=1,
+                              device_ops=[["fold", 4.0]],
+                              idle_gaps=[["DevicePut", 5.0],
+                                         ["bench.barrier_wait", 1.0]])
+    s = devtrace.combine([a, b])
+    assert s.n_devices == 2 and s.window_s == 10.0 and s.busy_s == 3.0
+    assert s.idle_share == pytest.approx(1 - 6 / 20)
+    assert s.device_ops == [["fold", 5.5], ["copy", 0.5]]
+    assert s.idle_gaps == [["bench.barrier_wait", 9.0], ["DevicePut", 5.0]]
+
+
+def test_recorded_worker_traces():
+    """Four workers' traces (g20-procs4.pagerank at scale 8 on the CPU)
+    reduce to one summary of four devices over one wall-clock window."""
+    import json
+
+    from jax.profiler import ProfileData
+
+    from bench.tests.record_trace import WORKERS, worker_dirs
+
+    with open(f"{WORKERS}/window.json") as f:
+        window = json.load(f)
+    lo, hi = window["wall_lo"], window["wall_hi"]
+    s = devtrace.summarize_workers(worker_dirs(), lo, hi)
+    assert s.n_devices == 4
+    assert s.window_s == pytest.approx(hi - lo, rel=1e-6)
+    # each worker alone, over the same window on its own trace's clock
+    alone, names, ops = [], {"no host span"}, {}
+    for d in worker_dirs():
+        pd = ProfileData.from_file(devtrace.find_xplane(d))
+        t0 = devtrace.profile_start_ns(pd)
+        devices, lines = devtrace._read(pd, devtrace.BARRIER_SPAN)
+        # the probe's barrier span marks one thread: the worker's main
+        assert len(devices) == 1 and len(lines) == 1
+        names |= {n for n, _, _ in lines[0]}
+        one = devtrace.reduce_window(devices, lines[0], lo * 1e9 - t0,
+                                     hi * 1e9 - t0, top=1 << 30)
+        assert 0 < one.busy_s < one.window_s
+        # the gaps and the busy time fill the window
+        assert (sum(v for _, v in one.idle_gaps) + one.busy_s
+                == pytest.approx(one.window_s, rel=1e-9))
+        for name, sec in one.device_ops:
+            ops[name] = ops.get(name, 0.0) + sec
+        alone.append(one)
+    assert s.busy_s == pytest.approx(sum(a.busy_s for a in alone) / 4,
+                                     rel=1e-12)
+    assert s.idle_share == pytest.approx(
+        1 - sum(a.busy_s for a in alone) / sum(a.window_s for a in alone))
+    # operations summed over the chips, the ten largest, largest first
+    assert 0 < len(s.device_ops) <= 10
+    for name, sec in s.device_ops:
+        assert sec == pytest.approx(ops[name], rel=1e-12)
+    assert [v for _, v in s.device_ops] == sorted(ops.values(),
+                                                  reverse=True)[:10]
+    # idle gaps are named after the workers' main threads' events, and
+    # the workers wait at the barrier with their devices idle
+    assert 0 < len(s.idle_gaps) <= 10
+    assert {n for n, _ in s.idle_gaps} <= names
+    assert devtrace.BARRIER_SPAN in {n for n, _ in s.idle_gaps}

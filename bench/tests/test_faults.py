@@ -1,15 +1,26 @@
 """The check catches a broken program: the configuration's lower-precision
 path (the control) and each fault a cell can have, planted under the timed
 path, make ``correct`` come out false. The look for a chip is skipped; the
-rest of the run is the benchmark's own."""
+rest of the run is the benchmark's own. In a ``launch="processes"`` cell
+the faults are planted inside the timed call's workers
+(``worker_faults/sitecustomize.py``), where the supersteps run."""
+
+import os
 
 import jax.numpy as jnp
 import pytest
 
+from bench import harness
 from bench.tests import tiny
 from repro.core import engine as eng
 
 CELLS = [w["name"] for w in tiny.spec()["workloads"]]
+WORKER_FAULTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "worker_faults")
+
+
+def _in_workers(name: str) -> bool:
+    return harness.launch_of(tiny.cell(name).config)[0] == "processes"
 
 
 def _state_unchanged(monkeypatch):
@@ -93,7 +104,11 @@ FAULTS = {"state_unchanged": _state_unchanged,
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 @pytest.mark.parametrize("name", CELLS)
 def test_fault_is_not_correct(name, fault, tmp_path, monkeypatch):
-    FAULTS[fault](monkeypatch)
+    if _in_workers(name):
+        monkeypatch.setattr(harness, "PROBE", WORKER_FAULTS)
+        monkeypatch.setenv("GRAPHD_TEST_FAULT", fault)
+    else:
+        FAULTS[fault](monkeypatch)
     result, checks = tiny.run(name, tmp_path)
     assert not result["correct"], checks
     assert result["failed"] == result["attempted"]
