@@ -36,6 +36,20 @@ from that superstep's end to the last one's; and what only the process
 that holds a chip can read (its memory peak, its trace, its compiles and
 its barrier waits) comes from the probe, ``bench/probe/sitecustomize.py``,
 which the harness loads into each worker of the timed call.
+
+A traffic that says ``"halts": true`` is a search that runs to its
+fixpoint, such as a BFS. Its unit of work is one whole search, as
+Graphalytics times it, so such a cell differs in other places: the
+warm-up is one whole search, whose seconds give ``K``, the number of
+searches the window runs; each search starts again from superstep 0
+(``GraphDEngine.run`` with no state) and runs until the program halts,
+under a cap of ``|V|`` supersteps; the window runs from the first timed
+search's start to the last one's last superstep; and every timed search
+is compared with the reference, which also gives the supersteps the
+search takes to its fixpoint. A program argument ``{"$source": {"seed":
+s}}`` is the search's source: a vertex drawn from the traffic's seed
+``s`` (:func:`resolve_sources`), the same vertex of the structure in
+every run, whose index the reference is handed.
 """
 
 from __future__ import annotations
@@ -250,6 +264,57 @@ class CompileClock:
 # the job
 # ---------------------------------------------------------------------------
 
+def draw_source(src, dst, n: int, seed: int) -> int:
+    """A search key by Graph500's rule: a vertex index drawn uniformly from
+    ``seed`` among the vertices with an edge, drawn again while the search
+    from it reaches fewer than half of the ``n`` vertices."""
+    from scipy.sparse import csr_matrix
+    from scipy.sparse.csgraph import breadth_first_order
+
+    has_edge = np.flatnonzero(np.bincount(src, minlength=n))
+    adj = csr_matrix((np.ones(src.size, np.int8), (src, dst)), shape=(n, n))
+    rng = np.random.default_rng(int(seed))
+    for _ in range(100):
+        v = int(rng.choice(has_edge))
+        reached = breadth_first_order(adj, v, return_predecessors=False)
+        if 2 * reached.size >= n:
+            return v
+    raise SpecError(f"no vertex drawn from seed {seed} reaches half of the "
+                    "graph")
+
+
+def resolve_sources(traffic: dict, src, dst, labels) -> dict:
+    """``traffic`` with its argument ``{"$source": {"seed": s}}``, if it has
+    one, resolved: the program gets ``{"$recoded_id": id}``, the id in this
+    run of the vertex :func:`draw_source` draws from ``s``, and the traffic
+    gets ``source_index``, that vertex's index, for the reference. The draw
+    reads the structure alone, so every run searches from the same vertex
+    of it."""
+    args = traffic["program"].get("args", {})
+    keys = [k for k, v in args.items()
+            if isinstance(v, dict) and "$source" in v]
+    if not keys:
+        return traffic
+    if len(keys) > 1:
+        raise SpecError(f"more than one $source argument: {keys}")
+    out = json.loads(json.dumps(traffic))
+    index = draw_source(src, dst, labels.size,
+                        args[keys[0]]["$source"]["seed"])
+    out["program"]["args"][keys[0]] = {"$recoded_id": int(labels[index])}
+    out["source_index"] = index
+    return out
+
+
+def check_control(cell: Cell) -> None:
+    """Fail unless the cell has a lower-precision path to run as the
+    control: a traffic whose answers no narrower type can change says so
+    under ``no_control``."""
+    if "no_control" in cell.traffic:
+        raise SpecError(f"{cell.name}: traffic {cell.workload['traffic']!r} "
+                        "has no precision control: "
+                        f"{cell.traffic['no_control']}")
+
+
 def make_program(traffic: dict, vertex_ids, n_shards: int):
     """The traffic's ``program``: a class of ``repro.core`` and its
     arguments; an argument ``{"$recoded_id": v}`` is vertex id ``v``'s
@@ -459,6 +524,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     cfg, traffic, ref = cell.config, cell.traffic, cell.reference
     launch, launch_opts = launch_of(cfg)
     procs = launch == "processes"
+    halts = bool(traffic.get("halts"))
+    if halts and procs:
+        raise SpecError(f"{cell.name}: a traffic that halts runs in one "
+                        "process only: launch=\"processes\" spawns its "
+                        "workers anew at every search")
+    if control:
+        check_control(cell)
     cache = cache or os.path.join(BENCH, ".cache")
     # the benchmark's data: made in a child process, which frees the chip
     # and its host memory before this process opens the chip
@@ -469,6 +541,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     labels = graphgen.labels(cfg["graph"], ids, seed)
     graph = Graph(src=labels[src], dst=labels[dst], weight=None,
                   vertex_ids=labels)
+    traffic = resolve_sources(traffic, src, dst, labels)
     data_s = time.perf_counter() - t0
     log(f"{cell.name}: graph {made['cache']} {made['seconds']:.3f} s, "
         f"labelled from the seed, {data_s:.3f} s in all (not set-up)")
@@ -511,27 +584,43 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                             f"asks for {cfg['plan']}")
         # warm-up: every program compiles or loads from the cache here
         compiled0 = clock.seconds
-        warm_steps = 2 if procs else 1
-        warm = job.run(max_supersteps=warm_steps)
-        first_step_s = warm.history[0].seconds
-        if procs:
-            # the first superstep spawned the workers: the second is a
-            # superstep alone
-            step_est = warm.history[1].seconds
-            if device is None:
-                device = worker_device(cell, warm.devices)
+        if halts:
+            cap = n  # supersteps: no search goes deeper than |V| levels
+            # one whole search: every superstep the timed searches run
+            t0 = time.perf_counter()
+            _, warm_history = job.engine.run(max_supersteps=cap)
+            warm_s = time.perf_counter() - t0
+            # the search less its compiles, floored as for a superstep
+            step_est = max(warm_s - (clock.seconds - compiled0),
+                           0.25 * warm_s)
+            k = max(1, round(seconds / step_est))
+            unit = "search"
         else:
-            # the warm-up superstep less its compiles; the floor keeps K
-            # from growing past a few times the window where they overlap
-            step_est = max(first_step_s - (clock.seconds - compiled0),
-                           0.25 * first_step_s)
-        k = max(2, round(seconds / step_est))
+            warm_steps = 2 if procs else 1
+            warm = job.run(max_supersteps=warm_steps)
+            warm_history = warm.history
+            if procs:
+                # the first superstep spawned the workers: the second is a
+                # superstep alone
+                step_est = warm_history[1].seconds
+                if device is None:
+                    device = worker_device(cell, warm.devices)
+            else:
+                # the warm-up superstep less its compiles; the floor keeps K
+                # from growing past a few times the window where they
+                # overlap
+                step_est = max(warm_history[0].seconds
+                               - (clock.seconds - compiled0),
+                               0.25 * warm_history[0].seconds)
+            k = max(2, round(seconds / step_est))
+            unit = "superstep"
+        first_step_s = warm_history[0].seconds
         log(f"{cell.name}: set-up built {clock.compiles} program(s), "
             f"{clock.misses} compiled anew "
             f"({'a cold run' if clock.misses else 'all from the cache'}), "
             f"{clock.seconds:.3f} s of tracing, lowering and compiling; "
             f"warm-up supersteps "
-            f"{[round(r.seconds, 3) for r in warm.history]} s, superstep "
+            f"{[round(r.seconds, 3) for r in warm_history]} s, {unit} "
             f"estimate {step_est:.3f} s -> K={k}")
 
         stamps = []  # (perf_counter, wall clock, record) per superstep
@@ -546,13 +635,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             opts.python_tracer_level = 0
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
         compiles0 = clock.compiles
-        # a processes call runs one more superstep: its first, the spawn
-        target = warm_steps + int(procs) + k
+        searches = []  # halts: (values, history, channel stats) a search
         t_call, wall_call = time.perf_counter(), time.time()
         try:
             with (probe or contextlib.nullcontext()), \
                     jax.profiler.TraceAnnotation(devtrace.RUN_SPAN):
-                res = job.run(max_supersteps=target, on_step=on_step)
+                if halts:
+                    for _ in range(k):
+                        (values, _), history = job.engine.run(
+                            max_supersteps=cap, on_step=on_step)
+                        stats = getattr(job.engine, "channel_stats", None)
+                        searches.append((values, history, dataclasses.asdict(
+                            stats) if stats else None))
+                else:
+                    # a processes call runs one more superstep: its first,
+                    # the spawn
+                    target = warm_steps + int(procs) + k
+                    res = job.run(max_supersteps=target, on_step=on_step)
         finally:
             if trace and not procs:
                 jax.profiler.stop_trace()
@@ -604,11 +703,26 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             stats = jax.devices()[0].memory_stats() or {}
             device = dict(device, memory_peak_bytes=stats.get(
                 "peak_bytes_in_use"))
-            stats = getattr(job.engine, "channel_stats", None)
-            channel = dataclasses.asdict(stats) if stats else None
-        steps = len(warm.history) + len(res.history)
-        got, missing = values_array(res.values, labels)
-        del res
+            if halts:
+                # each search's run starts the channel's stats afresh
+                per_search = [c for _, _, c in searches if c is not None]
+                channel = ({key: sum(c[key] for c in per_search)
+                            for key, v in per_search[0].items()
+                            if isinstance(v, (int, float))}
+                           if per_search else None)
+            else:
+                stats = getattr(job.engine, "channel_stats", None)
+                channel = dataclasses.asdict(stats) if stats else None
+        if halts:
+            steps = len(warm_history) + len(window)
+            answers = [values_array(job.engine.gather_values(values),
+                                    labels) + (len(history),)
+                       for values, history, _ in searches]
+            del searches
+        else:
+            steps = len(warm_history) + len(res.history)
+            got, missing = values_array(res.values, labels)
+            del res
         job.close(delete=True)
         del job
         summary = None
@@ -627,25 +741,44 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     # the check, after the window and with the job released
     t0 = time.perf_counter()
-    want = ref.run(src, dst, n, traffic, steps)
-    if "reference" in ctl:
-        # the reference one precision lower, in the program's place
-        got = ref.run(src, dst, n, traffic, steps, precision=ctl["reference"])
-    numbers = ref.compare(got, want, traffic)
-    limits = traffic["limits"]
-    checks = {k: dict(value=numbers[k], limit=limits[k]) for k in limits}
-    checks["missing_vertices"] = dict(value=missing, limit=0)
-    checks["missing_supersteps"] = dict(value=target - steps, limit=0)
-    correct = all(c["value"] <= c["limit"] for c in checks.values())
-    log(f"{cell.name}: reference {steps} supersteps in "
+    limits = dict(traffic["limits"], missing_vertices=0,
+                  missing_supersteps=0)
+    if halts:
+        # every timed search, against the search to its fixpoint
+        ref_steps = ref.supersteps(src, dst, n, traffic)
+        want = ref.run(src, dst, n, traffic, ref_steps)
+        readings = [dict(ref.compare(got, want, traffic),
+                         missing_vertices=missing,
+                         missing_supersteps=abs(ref_steps - taken))
+                    for got, missing, taken in answers]
+        attempted = len(readings)
+    else:
+        ref_steps = steps
+        want = ref.run(src, dst, n, traffic, steps)
+        if "reference" in ctl:
+            # the reference one precision lower, in the program's place
+            got = ref.run(src, dst, n, traffic, steps,
+                          precision=ctl["reference"])
+        readings = [dict(ref.compare(got, want, traffic),
+                         missing_vertices=missing,
+                         missing_supersteps=target - steps)]
+        attempted = steps
+    checks = {key: dict(value=max(r[key] for r in readings), limit=lim)
+              for key, lim in limits.items()}
+    bad = sum(any(not r[key] <= lim for key, lim in limits.items())
+              for r in readings)
+    correct = bad == 0
+    failed = (bad if halts else attempted) if bad else 0
+    log(f"{cell.name}: reference {ref_steps} supersteps in "
         f"{time.perf_counter() - t0:.3f} s")
 
-    per_step = ref.active_edges(src, dst, n, traffic, steps)
+    per_step = ref.active_edges(src, dst, n, traffic, ref_steps)
     run = RunRecord(
         cell=cell, n_vertices=n, setup_s=setup_s,
         partition_s=partition_s, first_step_s=first_step_s,
         window_s=window_s, window=window,
-        window_edges=int(sum(per_step[r.step] for r in window)),
+        window_edges=int(sum(per_step[r.step] for r in window
+                             if r.step < len(per_step))),
         plan_mode=p.mode, channel_stats=channel, trace=summary,
         device_kind=device["kind"], spawn_s=spawn_s,
         barrier_waits=barrier_waits)
@@ -654,9 +787,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         v = reader.read(run)
         if v is not None:
             metrics[entry["name"]] = dict(value=v, unit=entry["unit"])
-    result = dict(correct=bool(correct), attempted=steps,
-                  failed=0 if correct else steps, metrics=metrics,
-                  device=device)
+    result = dict(correct=bool(correct), attempted=attempted, failed=failed,
+                  metrics=metrics, device=device)
     if summary is not None:
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s

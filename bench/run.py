@@ -10,7 +10,8 @@ exit code 1 and no result, when JAX finds no TPU or fewer chips than the
 cell asks for: it never falls back to the CPU.
 
 The last line of standard output is one JSON object: ``correct``,
-``attempted`` and ``failed`` (supersteps), ``metrics`` (the cell's
+``attempted`` and ``failed`` (supersteps; for a traffic that halts,
+searches), ``metrics`` (the cell's
 end-to-end metrics, or with ``--trace 1`` its per-layer metrics),
 ``device``, with ``--trace 1`` a ``breakdown``, and last ``checks``: each
 number compared with the reference beside its limit. The same numbers
@@ -18,7 +19,8 @@ are the last lines of standard error.
 
 ``--control 1`` runs the configuration's lower-precision path instead of
 the configured plan: the control that the check must call not correct.
-The benchmark's own runs never pass it.
+The benchmark's own runs never pass it. A traffic with no such path (its
+``no_control``) exits with code 2 and a line saying so.
 """
 
 from __future__ import annotations
@@ -55,6 +57,8 @@ def main(argv=None) -> int:
     t_start = harness.process_start()
     try:
         cell = harness.resolve(harness.load_spec(ROOT), args.workload, ROOT)
+        if args.control:
+            harness.check_control(cell)
         harness.check_chips(cell)
     except harness.SpecError as e:
         print(f"bench: {e}", file=sys.stderr)

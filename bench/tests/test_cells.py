@@ -16,14 +16,21 @@ def test_cell_agrees_with_its_reference(name, tmp_path):
     result, checks = tiny.run(name, tmp_path)
     assert result["correct"], checks
     assert result["failed"] == 0
-    assert checks["max_rel_err"]["value"] < 1e-5
+    if "max_rel_err" in checks:
+        assert checks["max_rel_err"]["value"] < 1e-5
+    else:  # a search: levels are exact
+        assert checks["mismatched_vertices"]["value"] == 0
+        assert checks["missing_supersteps"]["value"] == 0
     assert checks["missing_vertices"]["value"] == 0
     want = {m["name"] for m in SPEC["end_to_end"]
             if name in m.get("workloads", [name])}
     assert set(result["metrics"]) == want
     for m in result["metrics"].values():
         assert m["value"] > 0
-    assert result["attempted"] >= 3  # the warm-up superstep and K >= 2
+    if tiny.cell(name).traffic.get("halts"):
+        assert result["attempted"] >= 1  # K >= 1 searches
+    else:
+        assert result["attempted"] >= 3  # the warm-up superstep and K >= 2
     assert set(result["device"]) >= {"platform", "kind", "count",
                                       "memory_peak_bytes"}
 

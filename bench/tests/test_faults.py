@@ -5,6 +5,7 @@ rest of the run is the benchmark's own. In a ``launch="processes"`` cell
 the faults are planted inside the timed call's workers
 (``worker_faults/sitecustomize.py``), where the supersteps run."""
 
+import math
 import os
 
 import jax.numpy as jnp
@@ -15,6 +16,8 @@ from bench.tests import tiny
 from repro.core import engine as eng
 
 CELLS = [w["name"] for w in tiny.spec()["workloads"]]
+# the cells whose traffic has a lower-precision path to run as the control
+CONTROLLED = [n for n in CELLS if "no_control" not in tiny.cell(n).traffic]
 WORKER_FAULTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "worker_faults")
 
@@ -83,12 +86,14 @@ def _no_exchange(monkeypatch):
 
 
 def _answer_altered(monkeypatch):
-    """One vertex's value is changed by 1 % where the job hands it out."""
+    """One vertex's value is changed by 1 % where the job hands it out: the
+    first whose value that changes (finite and not 0; a search's source
+    reads 0 and an unreached vertex inf)."""
     gather = eng.GraphDEngine.gather_values
 
     def altered(self, values):
         out = gather(self, values)
-        v = next(iter(out))
+        v = next(v for v, x in out.items() if math.isfinite(x) and x != 0)
         out[v] = out[v] * 1.01
         return out
 
@@ -112,10 +117,14 @@ def test_fault_is_not_correct(name, fault, tmp_path, monkeypatch):
     result, checks = tiny.run(name, tmp_path)
     assert not result["correct"], checks
     assert result["failed"] == result["attempted"]
-    assert checks["max_rel_err"]["value"] > checks["max_rel_err"]["limit"]
+    # each traffic's own numbers: PageRank's error, a search's levels or
+    # its halting superstep
+    own = ("max_rel_err",) if "max_rel_err" in checks else (
+        "mismatched_vertices", "missing_supersteps")
+    assert any(checks[k]["value"] > checks[k]["limit"] for k in own), checks
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CONTROLLED)
 def test_control_is_not_correct(name, tmp_path):
     """The lower-precision path the configuration names (bf16 messages)
     fails the limit that the float32 program meets."""

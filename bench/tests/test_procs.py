@@ -13,6 +13,8 @@ SPEC = tiny.spec()
 CELLS = [w["name"] for w in SPEC["workloads"]]
 PROCS = [n for n in CELLS
          if harness.launch_of(tiny.cell(n).config)[0] == "processes"]
+# a traffic that halts times whole searches (test_search.py)
+STEPPED = [n for n in CELLS if not tiny.cell(n).traffic.get("halts")]
 
 
 @pytest.mark.parametrize("name", PROCS)
@@ -33,7 +35,7 @@ def test_processes_run_leaves_the_chips_to_the_workers(name, tmp_path,
     assert len(result["device"]["memory_peak_bytes_per_chip"]) == 4
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", STEPPED)
 def test_warm_up_k_and_window(name, tmp_path, monkeypatch):
     """A cell in one process warms up one superstep and times ``K`` in a
     window that starts with the timed call; a processes cell warms up two
@@ -63,6 +65,11 @@ def test_warm_up_k_and_window(name, tmp_path, monkeypatch):
     assert calls == [first - int(procs), first + k]
     assert [r.step for r in rec.window] == list(range(first, first + k))
     assert result["attempted"] == first + k
+    # the checks of a run of supersteps: its error, and every vertex and
+    # superstep there
+    assert list(checks) == ["max_rel_err", "missing_vertices",
+                            "missing_supersteps"]
+    assert checks["missing_supersteps"] == dict(value=0, limit=0)
     assert (rec.spawn_s is not None) == procs
     assert result["metrics"]["edges_per_s"]["value"] == pytest.approx(
         rec.window_edges / rec.window_s)
